@@ -81,7 +81,7 @@ def run(sizes=SIZES, emit=print):
             timings = {}
             for label, spec in _variants():
                 sm = build_smoother(spec,
-                                    autotune_for=(B, n, model.nx)
+                                    autotune_for=(B, n, model.nx, jnp.float32)
                                     if spec.backend == "auto" else None)
                 fn = jax.jit(lambda ys, sm=sm: sm.iterate(model, ys).mean)
                 timings[label] = _time_fn(fn, ys)
@@ -108,8 +108,9 @@ def run_smoke(emit=print):
 
     # 1. backend="auto" never records a choice slower than fused-jnp.
     sm_auto = build_smoother(SmootherSpec(n_iter=N_ITER, lm_lambda=1.0),
-                             autotune_for=(B, n, model.nx))
-    entry = kc_autotune.lookup(sm_auto.spec_id, B, n, model.nx)
+                             autotune_for=(B, n, model.nx, jnp.float32))
+    entry = kc_autotune.lookup(sm_auto.spec_id, B, n, model.nx,
+                               jnp.float32)
     assert entry is not None, "autotune_for did not populate the cache"
     if entry["choice"] == kc_autotune.CHOICE_KERNEL:
         assert entry["kernel_us"] <= entry["fused_us"], entry

@@ -29,6 +29,7 @@ from __future__ import annotations
 import argparse
 import json
 import re
+import sys
 
 
 def _parse_derived(derived: str) -> dict:
@@ -77,6 +78,8 @@ def main() -> None:
     # extension); runtime benches pin float32 explicitly like the paper.
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
 
     rows = []
     print("name,us_per_call,derived")

@@ -38,9 +38,12 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import hashlib
 import sys
 from typing import Optional
+
+import jax
 
 from . import cost as _cost
 from . import iterated as _iterated
@@ -87,7 +90,7 @@ class SmootherSpec:
                             ``spec_id``; see :meth:`Smoother.autotune`),
                             "jnp" (fused twins only, never a kernel),
                             "tpu" / "gpu" (force that Pallas lowering;
-                            degrades to fused + warning off-platform).
+                            raises off-platform).
 
     Validation happens at construction: bad axis names or nonsensical
     iteration knobs raise ``ValueError`` immediately instead of failing
@@ -202,6 +205,18 @@ class SmootherSpec:
             damping=self.damping, backend=self.backend)
 
 
+def _full_precision(method):
+    """Trace ``method`` with float32 matmuls at full precision. On the
+    TPU a float32 matmul at the default precision rounds its operands to
+    bfloat16, which the covariance updates (``P - K S K^T``) cannot
+    afford; elsewhere the setting changes nothing."""
+    @functools.wraps(method)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return method(*args, **kwargs)
+    return traced
+
+
 class Smoother:
     """Configured estimator built by :func:`build_smoother`.
 
@@ -230,17 +245,21 @@ class Smoother:
 
     @staticmethod
     def _launch_shape(ys, m0):
-        """Static ``(B, T, nx)`` of a batched call site (None for single
-        trajectories) — the ``backend="auto"`` autotune-cache key."""
+        """Static ``(B, T, nx, dtype)`` of a batched call site (None for
+        single trajectories) — the ``backend="auto"`` autotune-cache
+        key."""
         if ys.ndim != 3:
             return None
-        return (int(ys.shape[0]), int(ys.shape[1]), int(m0.shape[-1]))
+        return (int(ys.shape[0]), int(ys.shape[1]), int(m0.shape[-1]),
+                ys.dtype)
 
     # -- backend autotuning -------------------------------------------------
 
-    def autotune(self, B: int, n: int, nx: int) -> dict:
+    def autotune(self, B: int, n: int, nx: int, dtype) -> dict:
         """Measure compiled-kernel vs fused-jnp combine for ``(B, n, nx)``
-        launches and cache the winner under this smoother's ``spec_id``.
+        launches in ``dtype`` (the dtype the launches run in; the
+        measurement is made in it and it keys the verdict) and cache the
+        winner under this smoother's ``spec_id``.
 
         Host-side and idempotent per shape: `build_smoother` (via
         ``autotune_for``) and server warmup call this once per bucket
@@ -252,10 +271,11 @@ class Smoother:
         entry ``{choice, backend, kernel_us, fused_us}``.
         """
         from repro.kernels.kalman_combine import autotune as _at
-        return _at.autotune(self.spec_id, B, n, nx)
+        return _at.autotune(self.spec_id, B, n, nx, dtype)
 
     # -- one linearized pass ------------------------------------------------
 
+    @_full_precision
     def filter(self, lin, ys, m0, P0):
         """One filtering pass over an already-linearized SSM.
 
@@ -277,6 +297,7 @@ class Smoother:
                   combine_impl=self.config.resolved_combine_impl(
                       batched, shape=self._launch_shape(ys, m0)))
 
+    @_full_precision
     def smooth(self, lin, ys, m0, P0):
         """One filtering + smoothing pass over a linearized SSM.
 
@@ -300,6 +321,7 @@ class Smoother:
 
     # -- the full iterated smoother ----------------------------------------
 
+    @_full_precision
     def iterate(self, model, ys, init=None, return_history: bool = False,
                 return_info: bool = False):
         """Run the iterated smoother (IEKS/IPLS per the spec) on a
@@ -314,6 +336,7 @@ class Smoother:
 
     __call__ = iterate
 
+    @_full_precision
     def log_likelihood(self, model, ys, traj, per_step: bool = False):
         """Measurement log-likelihood of ``ys`` under the smoothed
         posterior ``traj`` (the spec's linearization family); scalar for
@@ -322,6 +345,7 @@ class Smoother:
         return _iterated.smoothed_log_likelihood(
             model, ys, traj, self.config, per_step=per_step)
 
+    @_full_precision
     def cost(self, model, ys, traj):
         """Gauss-Newton smoothing cost of ``traj`` under the spec's
         linearization family (`core.cost.gn_cost`) — the objective
@@ -340,8 +364,8 @@ def build_smoother(spec: Optional[SmootherSpec] = None, *,
     Field overrides may be passed directly instead of a spec
     (``build_smoother(linearization="slr", n_iter=5)``).
 
-    ``autotune_for=(B, n, nx)`` runs :meth:`Smoother.autotune` for that
-    launch shape before returning, so ``backend="auto"`` call sites of
+    ``autotune_for=(B, n, nx, dtype)`` runs :meth:`Smoother.autotune` for
+    that launch shape before returning, so ``backend="auto"`` call sites of
     the shape dispatch to the measured winner from the first trace.
     Cached per ``(spec_id, shape)`` — repeated builds don't re-measure.
     """

@@ -135,19 +135,19 @@ class IteratedConfig:
                               shape: Optional[tuple] = None) -> str:
         """The scan-driver ``combine_impl`` string for one call site.
 
-        ``shape`` is the static launch shape ``(B, T, nx)`` when the
-        caller knows it (the batched pass drivers do) — it keys the
+        ``shape`` is the static launch shape ``(B, T, nx, dtype)`` when
+        the caller knows it (the batched pass drivers do) — it keys the
         ``backend="auto"`` autotune-cache lookup. Resolution:
 
           * explicit ``combine_impl`` wins; "pallas" is qualified to
             "pallas:tpu"/"pallas:gpu" when the backend forces a lowering
-            (off-platform the scan driver degrades it to fused + warns);
+            (off-platform the scan driver raises: no silent fused run);
           * "auto" + single trajectory -> "jnp" (textbook vmap);
           * "auto" + batched: ``backend="jnp"`` -> "fused";
             ``backend="tpu"/"gpu"`` -> that compiled kernel;
             ``backend="auto"`` -> the measured winner recorded by
             `repro.kernels.kalman_combine.autotune` for
-            ``(model_id, B, T, nx)`` — ``model_id`` carries the spec_id
+            ``(model_id, B, T, nx, dtype)`` — ``model_id`` carries the spec_id
             on API-built smoothers — else the fused twin (the safe
             default: an unmeasured site is never slower than fused).
 
@@ -302,7 +302,7 @@ def _one_pass_batched(model: StateSpaceModel, ys: jnp.ndarray,
                 combine_impl=cfg.resolved_combine_impl(
                     batched=True,
                     shape=(ys.shape[0], ys.shape[1],
-                           traj.mean.shape[-1])))
+                           traj.mean.shape[-1], ys.dtype)))
     else:
         _, smoothed = sequential._filter_smoother_batched(
             lin, ys_eff, model.m0, model.P0)

@@ -102,6 +102,15 @@ def symmetrize(M: jnp.ndarray) -> jnp.ndarray:
     return 0.5 * (M + jnp.swapaxes(M, -1, -2))
 
 
+def eye32(n: int, dtype) -> jnp.ndarray:
+    """``jnp.eye(n, dtype=dtype)`` built from int32 iotas. `jnp.eye`'s
+    iotas are int64 when float64 is enabled, and Mosaic has no 64-bit
+    vector layout: inside a TPU kernel that aborts the compiler."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return (rows == cols).astype(dtype)
+
+
 def gauss_jordan_inverse(W: jnp.ndarray) -> jnp.ndarray:
     """Batched inverse of ``[..., n, n]`` via Gauss-Jordan, unrolled over n.
 
@@ -115,7 +124,7 @@ def gauss_jordan_inverse(W: jnp.ndarray) -> jnp.ndarray:
     kernel (the 2D iota keeps Mosaic happy).
     """
     n = W.shape[-1]
-    eye = jnp.eye(n, dtype=W.dtype)
+    eye = eye32(n, W.dtype)
     aug = jnp.concatenate(
         [W, jnp.broadcast_to(eye, W.shape[:-2] + (n, n))], axis=-1)
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)

@@ -4,8 +4,8 @@ The compiled combine kernel wins when one Blelloch level carries enough
 element pairs to amortize the launch; below that, XLA's fused jnp twin
 wins. The crossover depends on the host (arXiv 2511.10363 measures
 exactly this span-vs-work regime on GPUs), so "auto" does not guess: it
-*times* both paths for the call site's ``(B, T, nx)`` once and caches
-the winner in a ``spec_id``-keyed in-process table.
+*times* both paths for the call site's ``(B, T, nx, dtype)`` once and
+caches the winner in a ``spec_id``-keyed in-process table.
 
 Contract (DESIGN.md §12):
   * `decide` is consulted at trace time and therefore NEVER measures —
@@ -18,8 +18,11 @@ Contract (DESIGN.md §12):
   * on hosts with no compiled lowering (CPU) there is nothing to
     measure: the choice is "fused" without timing anything — interpret
     mode is never a candidate;
-  * repeated builds and warmups for the same ``(spec_id, B, T, nx)``
-    hit the cache and do not re-measure.
+  * repeated builds and warmups for the same ``(spec_id, B, T, nx,
+    dtype)`` hit the cache and do not re-measure;
+  * the measurement runs in the call site's dtype, and the dtype is in
+    the key: a float32 verdict never routes a float64 trace to a kernel
+    that has only a float32 lowering.
 """
 from __future__ import annotations
 
@@ -40,32 +43,33 @@ _REPS = 3
 CHOICE_KERNEL = "pallas"
 CHOICE_FUSED = "fused"
 
-Key = Tuple[str, str, int, int, int]
+Key = Tuple[str, str, int, int, int, str]
 
 _cache: Dict[Key, dict] = {}
 
 
-def cache_key(spec_id: str, B: int, T: int, nx: int) -> Key:
-    """One entry per (spec identity, launch shape, host platform). The
-    platform rides in the key so a cache serialized across processes
+def cache_key(spec_id: str, B: int, T: int, nx: int, dtype) -> Key:
+    """One entry per (spec identity, launch shape, dtype, host platform).
+    The platform rides in the key so a cache serialized across processes
     (not done today — the table is in-process) could never leak a GPU
     verdict onto a CPU host."""
-    return (str(spec_id), jax.default_backend(), int(B), int(T), int(nx))
+    return (str(spec_id), jax.default_backend(), int(B), int(T), int(nx),
+            jnp.dtype(dtype).name)
 
 
-def lookup(spec_id: str, B: int, T: int, nx: int) -> Optional[dict]:
-    return _cache.get(cache_key(spec_id, B, T, nx))
+def lookup(spec_id: str, B: int, T: int, nx: int, dtype) -> Optional[dict]:
+    return _cache.get(cache_key(spec_id, B, T, nx, dtype))
 
 
 def decide(spec_id: str, B: Optional[int], T: Optional[int],
-           nx: Optional[int]) -> str:
+           nx: Optional[int], dtype=None) -> str:
     """Trace-time choice for ``backend="auto"``: the cached measured
     winner, else the fused twin. Pure lookup — never measures, so it is
     safe to call while tracing and is trace-stable for a given cache
     state (warmup populates the cache *before* the executable traces)."""
-    if B is None or T is None or nx is None:
+    if B is None or T is None or nx is None or dtype is None:
         return CHOICE_FUSED
-    entry = lookup(spec_id, B, T, nx)
+    entry = lookup(spec_id, B, T, nx, dtype)
     if entry is None:
         return CHOICE_FUSED
     return entry["choice"]
@@ -77,11 +81,11 @@ def clear_cache() -> None:
 
 def cache_entries() -> Dict[str, dict]:
     """Readable snapshot (serving surfaces this in service stats):
-    ``"spec_id@platform/B=../T=../nx=.." -> {choice, kernel_us,
+    ``"spec_id@platform/B=../T=../nx=../dtype" -> {choice, kernel_us,
     fused_us}``."""
     return {
-        f"{sid}@{plat}/B={B}/T={T}/nx={nx}": dict(entry)
-        for (sid, plat, B, T, nx), entry in sorted(_cache.items())
+        f"{sid}@{plat}/B={B}/T={T}/nx={nx}/{dt}": dict(entry)
+        for (sid, plat, B, T, nx, dt), entry in sorted(_cache.items())
     }
 
 
@@ -114,10 +118,10 @@ def _level_elements(n_pairs: int, nx: int, dtype):
     return e
 
 
-def autotune(spec_id: str, B: int, T: int, nx: int,
-             dtype=jnp.float32) -> dict:
-    """Measure kernel vs fused-jnp for one launch shape and cache the
-    winner. Idempotent per key; returns the cache entry.
+def autotune(spec_id: str, B: int, T: int, nx: int, dtype) -> dict:
+    """Measure kernel vs fused-jnp for one launch shape in ``dtype`` (the
+    dtype the call site runs) and cache the winner. Idempotent per key;
+    returns the cache entry.
 
     The probe is the filtering combine at the scan's *top level*
     (``B * T / 2`` pairs — the widest, most kernel-favorable level; if
@@ -125,7 +129,7 @@ def autotune(spec_id: str, B: int, T: int, nx: int,
     shrink, so picking by the top level can flip a win to "fused" on a
     borderline site but never selects a slower-than-fused path).
     """
-    key = cache_key(spec_id, B, T, nx)
+    key = cache_key(spec_id, B, T, nx, dtype)
     if key in _cache:
         return _cache[key]
     backend = _ops.kernel_backend()

@@ -5,9 +5,12 @@ Dispatch policy (DESIGN.md §2/§12):
   * GPU backend -> compiled Pallas (Triton) kernel (`triton.py`);
   * CPU / no compiled lowering -> the fused jnp twins. Interpret-mode
     pallas is *never* a dispatch target: it is orders of magnitude
-    slower than the fused twins, so forcing ``combine_impl="pallas"``
-    where only interpret mode exists falls back to the fused path and
-    warns once per process.
+    slower than the fused twins, so ``combine_impl="pallas"`` (the
+    platform's lowering, whatever it is) where only interpret mode exists
+    falls back to the fused path and warns once per process. A forced
+    lowering that the host lacks (``backend="tpu"`` off a TPU) raises:
+    the caller asked for that device, and a fused run would hide that it
+    is not there.
 
 The kernel-vs-reference choice is **trace-stable**: it is made once per
 call site from the *total* element count of the scan (`select_impl`), not
@@ -70,9 +73,9 @@ def resolve_backend(requested: Optional[str] = None) -> Optional[str]:
     fused/ref path (the off-accelerator dispatch bugfix: interpret-mode
     pallas is pathologically slower than the fused twins and must never
     be the silent default). An explicit "tpu"/"gpu" that does not match
-    the host also degrades to ``None`` with a one-time warning — forcing
-    a Mosaic kernel on CPU can only mean interpret mode. "interpret" is
-    honored as requested (tests opt in deliberately).
+    the host raises ``RuntimeError``: it names a device, and running the
+    fused twin instead would pass off a CPU run as that device's.
+    "interpret" is honored as requested (tests opt in deliberately).
     """
     have = kernel_backend()
     if requested is None:
@@ -91,12 +94,11 @@ def resolve_backend(requested: Optional[str] = None) -> Optional[str]:
         raise ValueError(f"unknown kernel backend {requested!r}; "
                          f"available: {sorted(KERNEL_BACKENDS)}")
     if requested != have:
-        _warn_once(
-            f"pallas-wrong-platform-{requested}",
-            f'backend="{requested}" kernels cannot compile on host '
-            f'platform "{jax.default_backend()}" — falling back to the '
-            "fused jnp combine.")
-        return None
+        raise RuntimeError(
+            f'backend="{requested}" forces the compiled {requested} '
+            f'kernel, but the host platform is "{jax.default_backend()}" '
+            f"(devices: {jax.devices()}); no {requested} device is "
+            'attached. Use backend="auto" to let the platform decide.')
     return requested
 
 
@@ -121,7 +123,7 @@ def select_impl(total_elems: Optional[int],
     return "kernel"
 
 
-def _kernel_call(combine_kind: str, ei, ej, tile: int, backend: str):
+def _kernel_call(combine_kind: str, ei, ej, backend: str):
     if backend == "gpu":
         from . import triton as _t
         fn = (_t.filtering_combine_batched_triton if combine_kind == "f"
@@ -131,10 +133,10 @@ def _kernel_call(combine_kind: str, ei, ej, tile: int, backend: str):
     # interpret mode (explicit test/debug opt-in only).
     fn = (_k.filtering_combine_batched if combine_kind == "f"
           else _k.smoothing_combine_batched)
-    return fn(ei, ej, tile=tile, interpret=backend == "interpret")
+    return fn(ei, ej, interpret=backend == "interpret")
 
 
-def filtering_combine_op(ei, ej, *, tile: int = 512, impl: str = "auto",
+def filtering_combine_op(ei, ej, *, impl: str = "auto",
                          backend: Optional[str] = None):
     B = ei.b.shape[0]
     if impl == "auto":
@@ -149,10 +151,10 @@ def filtering_combine_op(ei, ej, *, tile: int = 512, impl: str = "auto",
     kb = backend if backend is not None else kernel_backend()
     if kb is None:
         return _k.filtering_combine_batched_jnp(ei, ej)
-    return _kernel_call("f", ei, ej, tile, kb)
+    return _kernel_call("f", ei, ej, kb)
 
 
-def smoothing_combine_op(ei, ej, *, tile: int = 512, impl: str = "auto",
+def smoothing_combine_op(ei, ej, *, impl: str = "auto",
                          backend: Optional[str] = None):
     B = ei.g.shape[0]
     if impl == "auto":
@@ -164,7 +166,7 @@ def smoothing_combine_op(ei, ej, *, tile: int = 512, impl: str = "auto",
     kb = backend if backend is not None else kernel_backend()
     if kb is None:
         return _k.smoothing_combine_batched_jnp(ei, ej)
-    return _kernel_call("s", ei, ej, tile, kb)
+    return _kernel_call("s", ei, ej, kb)
 
 
 def batched_combine_for(combine, total_elems: Optional[int] = None,

@@ -11,9 +11,9 @@ GPU adaptation vs the TPU variant (DESIGN.md §3): the batch axis is
 tiled across *programs* (one CTA per ``TB``-element block) rather than
 VMEM blocks, and the tile is sized for register pressure, not VMEM
 capacity — the unrolled nx-side algebra holds ~10 live ``[TB, nx, nx]``
-intermediates, so the default ``TB`` is much smaller than the TPU
-kernel's 512. ``num_warps=4`` matches one 128-lane block per tile row;
-the nx loops are fully unrolled at trace time exactly as on TPU (state
+intermediates, so the default ``TB`` is one small constant here, where
+the TPU kernel sizes its tile per nx (`kalman_combine.block_rows`).
+``num_warps=4`` matches one 128-lane block per tile row; the nx loops are fully unrolled at trace time exactly as on TPU (state
 dims are tiny, nx <= 16).
 
 Off-GPU these wrappers run in interpret mode — that is a *test* path
@@ -40,8 +40,7 @@ _TILE = 128
 
 
 def _compiler_params(num_warps: int, num_stages: int):
-    return plgpu.TritonCompilerParams(num_warps=num_warps,
-                                      num_stages=num_stages)
+    return plgpu.CompilerParams(num_warps=num_warps, num_stages=num_stages)
 
 
 def _combine_call(kernel, num_fields, ei, ej, B, nx, tile, interpret,

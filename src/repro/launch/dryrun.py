@@ -34,6 +34,10 @@ from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.roofline import roofline_report
 from repro.launch.steps import make_cell_plan
 
+#: The chip the production meshes are made of (a `CHIP_PEAKS` key): the
+#: dry-run compiles on host devices, which have no peaks of their own.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              verbose: bool = True) -> dict:
@@ -85,7 +89,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "xla_cost_raw": {k: float(raw_cost.get(k, 0.0))
                          for k in ("flops", "bytes accessed")},
     }
-    result["roofline"] = roofline_report(cfg, shape, result)
+    result["roofline"] = roofline_report(cfg, shape, result,
+                                         TARGET_DEVICE_KIND)
     fits = arg_bytes < 16 * 2 ** 30
     result["fits_hbm16"] = bool(fits)
     if verbose:

@@ -5,9 +5,11 @@ via `repro.launch.hlo_analysis` (exact per-chip FLOPs / HBM traffic /
 collective bytes, with while-loop trip counts applied — see that module
 for why raw ``cost_analysis()`` under-counts scanned models):
 
-  compute_term    = FLOPs_per_chip / 197e12            [bf16 MXU peak]
-  memory_term     = HBM_bytes_per_chip / 819e9         [HBM bandwidth]
-  collective_term = collective bytes_per_chip / 50e9   [ICI]
+  compute_term    = FLOPs_per_chip / peak FLOP/s       [bf16 MXU peak]
+  memory_term     = HBM_bytes_per_chip / HBM bytes/s   [HBM bandwidth]
+  collective_term = collective bytes_per_chip / ICI    [per link]
+
+with the peaks of the chip the step targets, from `CHIP_PEAKS`.
 
 MODEL_FLOPS = 6*N*D (dense) or 6*N_active*D (MoE) for train; 2*N*D for
 single forward (prefill); 2*N*B for one decode step. The ratio
@@ -17,14 +19,36 @@ block-skipping and padded-head waste show up here too).
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import NamedTuple
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.hlo_analysis import analyze_hlo  # noqa: F401 (re-export)
 
-PEAK_FLOPS = 197e12     # bf16 / chip (TPU v5e-class target)
-HBM_BW = 819e9          # bytes/s per chip
-ICI_BW = 50e9           # bytes/s per link per chip
+
+class ChipPeaks(NamedTuple):
+    flops: float     # bf16 FLOP/s per chip
+    hbm_bw: float    # HBM bytes/s per chip
+    ici_bw: float    # interconnect bytes/s per link
+
+
+#: Published per-chip peaks, keyed by `jax.Device.device_kind`.
+#: "TPU v5 lite" is the TPU v5e; source: Google Cloud documentation,
+#: "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+#: chip-to-chip interconnect = 200 GB/s over 4 links).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a chip missing from `CHIP_PEAKS` is
+    an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                       ) from None
 
 
 def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
@@ -39,14 +63,16 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
     return 2.0 * n_active * shape.global_batch
 
 
-def roofline_report(cfg: ModelConfig, shape: ShapeConfig, cell: dict
-                    ) -> dict:
+def roofline_report(cfg: ModelConfig, shape: ShapeConfig, cell: dict,
+                    device_kind: str) -> dict:
     """``cell`` carries per-chip 'flops', 'hbm_bytes', 'collective_bytes'
-    from `analyze_hlo` plus 'chips'."""
+    from `analyze_hlo` plus 'chips'; ``device_kind`` names the chip the
+    step targets (a key of `CHIP_PEAKS`)."""
+    peaks = chip_peaks(device_kind)
     chips = cell["chips"]
-    compute_term = cell["flops"] / PEAK_FLOPS
-    memory_term = cell["hbm_bytes"] / HBM_BW
-    collective_term = cell["collective_bytes"]["total"] / ICI_BW
+    compute_term = cell["flops"] / peaks.flops
+    memory_term = cell["hbm_bytes"] / peaks.hbm_bw
+    collective_term = cell["collective_bytes"]["total"] / peaks.ici_bw
     terms = {"compute_s": compute_term, "memory_s": memory_term,
              "collective_s": collective_term}
     dominant = max(terms, key=terms.get)
@@ -54,7 +80,7 @@ def roofline_report(cfg: ModelConfig, shape: ShapeConfig, cell: dict
     step_time = max(terms.values())
     # Roofline fraction: useful-FLOPs rate vs peak, if the step ran at the
     # dominant-term bound (the CPU-container stand-in for measured MFU).
-    frac = (mf / chips / PEAK_FLOPS) / step_time if step_time > 0 else 0.0
+    frac = (mf / chips / peaks.flops) / step_time if step_time > 0 else 0.0
     total_hlo_flops = cell["flops"] * chips
     return {
         **{k: float(f"{v:.6g}") for k, v in terms.items()},

@@ -176,6 +176,24 @@ class SmootherServeConfig:
         return ChaosConfig.at_rate(self.chaos_rate, seed=self.chaos_seed)
 
 
+def serve_dtype(cfg: SmootherServeConfig):
+    """The dtype the service runs in, from ``cfg.f64``.
+
+    float64 does not run on the TPU: XLA:TPU implements its LU
+    decomposition (the smoother's linear solves) in float32 only, so the
+    bucket executable fails to compile. Refuse it here, with a clear
+    message, instead of failing inside the first launch."""
+    if not cfg.f64:
+        return jnp.float32
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            "float64 serving does not run on the TPU (XLA:TPU has no "
+            "float64 LU decomposition); serve in float32: --f32 on the "
+            "CLI, SmootherServeConfig(f64=False) in code")
+    jax.config.update("jax_enable_x64", True)
+    return jnp.float64
+
+
 def pad_requests(batch: List[np.ndarray], n_pad: int, b_pad: int,
                  R: np.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Pad a bucket of measurement sequences to ``[b_pad, n_pad, ny]``.
@@ -357,7 +375,8 @@ class SmootherServer:
                 # on hosts with no compiled lowering it records "fused"
                 # without timing anything).
                 if self.spec.backend == "auto":
-                    self._smoother.autotune(b_pad, n_pad, self.model.nx)
+                    self._smoother.autotune(b_pad, n_pad, self.model.nx,
+                                            self.model.m0.dtype)
                 key = self._icfg.cache_key(n_pad, b_pad, self.model.nx)
                 if key not in self.signatures_seen:
                     self.smooth_batch(dummy, n_pad, b_pad)  # compile
@@ -469,6 +488,7 @@ class SmootherServer:
 
         results: List[Optional[np.ndarray]] = [None] * len(requests)
         logliks: List[Optional[float]] = [None] * len(requests)
+        verdicts: Dict[str, int] = defaultdict(int)
         launches = 0
         iters_total = 0
         t0 = time.perf_counter()
@@ -481,11 +501,13 @@ class SmootherServer:
                 # (autobatch.pad_width): one bounded executable-cache
                 # contract whether requests arrive one-shot or queued.
                 b_pad = pad_width(len(chunk), self.cfg.max_batch)
-                means, info, lls, _ = self.smooth_batch(
+                means, info, lls, health = self.smooth_batch(
                     [requests[i] for i in chunk], n_pad, b_pad)
-                for i, m, ll in zip(chunk, means, lls):
+                for i, m, ll, ok in zip(chunk, means, lls, health):
                     results[i] = m
                     logliks[i] = ll
+                    # No retry lane here: a diverged lane is reported.
+                    verdicts[VERDICT_OK if ok else VERDICT_DIVERGED] += 1
                 launches += 1
                 iters_total += int(np.sum(np.asarray(
                     info.iterations)[:len(chunk)]))
@@ -495,6 +517,8 @@ class SmootherServer:
             "logliks": logliks,
             "requests": len(requests),
             "launches": launches,
+            "verdicts": dict(verdicts),
+            "compiles": len(self.signatures_seen),
             "mean_iterations": iters_total / max(len(requests), 1),
             "wall_s": dt,
             "traj_per_s": len(requests) / dt,
@@ -580,6 +604,7 @@ class SmootherServer:
             "mean_iterations": iters_total / max(len(requests), 1),
             "compiles": len(self.signatures_seen),
             "records": service["records"],
+            "launch_log": service["launches"],
             "backend_choices": _backend_choices(),
             "chaos": (injector.summary() if injector is not None
                       else None),
@@ -679,7 +704,7 @@ class MultiTenantServer:
 
         if not tenants:
             raise ValueError("need at least one tenant")
-        dtype = jnp.float64 if cfg.f64 else jnp.float32
+        dtype = serve_dtype(cfg)
         self.cfg = cfg
         self.specs: Dict[str, TenantSpec] = {}
         self.servers: Dict[str, SmootherServer] = {}
@@ -853,8 +878,6 @@ def serve_smoother_multitenant(cfg: SmootherServeConfig,
     multi-tenant queue. Tenants are drawn by ``weight`` per request;
     lengths follow the same varied-length mix as the single-tenant
     driver. ``--arrival none`` degenerates to an all-at-t=0 stream."""
-    if cfg.f64:
-        jax.config.update("jax_enable_x64", True)
     server = MultiTenantServer(tenants, cfg)
     requests, truths = make_tenant_fleet(server, cfg.requests, cfg.n,
                                          cfg.vary_lengths, cfg.seed)
@@ -895,16 +918,10 @@ def serve_smoother_multitenant(cfg: SmootherServeConfig,
     return stats
 
 
-def serve_smoother(cfg: SmootherServeConfig, emit=print) -> dict:
-    """Generate a synthetic coordinated-turn request fleet and serve it."""
-    from repro.scenarios import get_scenario
-
-    dtype = jnp.float64 if cfg.f64 else jnp.float32
-    if cfg.f64:
-        jax.config.update("jax_enable_x64", True)
-    sc = get_scenario("coordinated_turn")
-    model = sc.make_model(dtype)
-
+def make_fleet(sc, model, cfg: SmootherServeConfig):
+    """The single-tenant request fleet of `serve_smoother`, from
+    ``cfg.seed``: ``cfg.requests`` trajectories of scenario ``sc``.
+    Returns ``(requests [ys], truths [xs])``."""
     # A small set of distinct lengths keeps request generation cheap while
     # still exercising the (n, nx) bucketing + padding path.
     lengths = ([max(cfg.n // 2, 2), max((3 * cfg.n) // 4, 2), cfg.n]
@@ -916,6 +933,17 @@ def serve_smoother(cfg: SmootherServeConfig, emit=print) -> dict:
         xs, ys = sc.simulate(model, n_i, jax.random.PRNGKey(cfg.seed + i))
         requests.append(np.asarray(ys))
         truths.append(np.asarray(xs))
+    return requests, truths
+
+
+def serve_smoother(cfg: SmootherServeConfig, emit=print) -> dict:
+    """Generate a synthetic coordinated-turn request fleet and serve it."""
+    from repro.scenarios import get_scenario
+
+    dtype = serve_dtype(cfg)
+    sc = get_scenario("coordinated_turn")
+    model = sc.make_model(dtype)
+    requests, truths = make_fleet(sc, model, cfg)
 
     # Single-tenant smoother knobs from SmootherServeConfig lifted onto
     # the scenario's spec (the registry model_id rides inside spec_id —
@@ -991,6 +1019,8 @@ def main(argv=None):
                         "exceptions + stragglers; streaming mode only)")
     p.add_argument("--chaos-seed", type=int, default=0)
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.workload == "smoother":
         cfg = SmootherServeConfig(
             requests=args.requests, n=args.n, max_batch=args.max_batch,

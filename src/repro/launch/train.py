@@ -23,7 +23,7 @@ from repro.configs import get_config, reduced_config
 from repro.configs.base import ShapeConfig
 from repro.data.tokens import SyntheticTokenPipeline, TokenPipelineConfig
 from repro.launch import sharding as shard_lib
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.steps import (AdamWConfig, TrainState, make_train_step)
 from repro.models import init_model
 from repro.optim import init_adamw
@@ -50,7 +50,7 @@ class TrainLoopConfig:
 def build_mesh(loop_cfg: TrainLoopConfig):
     if loop_cfg.mesh_shape is None:
         return make_production_mesh()
-    return jax.make_mesh(loop_cfg.mesh_shape, ("data", "model"))
+    return make_mesh(loop_cfg.mesh_shape, ("data", "model"))
 
 
 def train(loop_cfg: TrainLoopConfig, emit=print) -> dict:

@@ -189,7 +189,6 @@ def _moe_layer_ep(params, x: jnp.ndarray, cfg: ModelConfig, mesh
     all_to_all each way over 'model'), and expert FLOPs shard over
     data x model. Replaces the global path's replicated token-sorted
     gathers (TBs/chip) with ~n_loc*k*d bucket traffic."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     E, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -261,13 +260,13 @@ def _moe_layer_ep(params, x: jnp.ndarray, cfg: ModelConfig, mesh
         shared_specs = (P(None, "model"), P(None, "model"),
                         P("model", None))
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(batch_ax, "model", None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None), shared_specs),
         out_specs=(P(batch_ax, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"], shared_in)
     return out, aux.astype(jnp.float32)

@@ -183,7 +183,6 @@ def _mlstm_sp(q, k, v, lf, li, CT: int, mesh):
     EXPERIMENTS.md §Perf, xlstm iteration 2)."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.scan import device_exclusive_scan
@@ -205,9 +204,9 @@ def _mlstm_sp(q, k, v, lf, li, CT: int, mesh):
 
     spec4 = P(batch_ax, None, "model", None)
     spec3 = P(batch_ax, None, "model")
-    return shard_map(local_fn, mesh=mesh,
+    return jax.shard_map(local_fn, mesh=mesh,
                      in_specs=(spec4, spec4, spec4, spec3, spec3),
-                     out_specs=spec4, check_rep=False)(q, k, v, lf, li)
+                     out_specs=spec4, check_vma=False)(q, k, v, lf, li)
 
 
 def mlstm_layer(params, x, cfg: ModelConfig, *,

@@ -14,7 +14,6 @@ import jax, jax.numpy as jnp, numpy as np
 jax.config.update("jax_enable_x64", True)
 from functools import partial
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core import (filtering_combine, filtering_identity,
                         smoothing_combine, smoothing_identity,
                         sharded_associative_scan, associative_scan,
@@ -41,12 +40,12 @@ se = SmoothingElement(
 spec_f = FilteringElement(A=P("sp"), b=P("sp"), C=P("sp"), eta=P("sp"), J=P("sp"))
 spec_s = SmoothingElement(E=P("sp"), g=P("sp"), L=P("sp"))
 
-@partial(shard_map, mesh=mesh, in_specs=(spec_f,), out_specs=spec_f)
+@partial(jax.shard_map, mesh=mesh, in_specs=(spec_f,), out_specs=spec_f)
 def sharded_prefix(e):
     return sharded_associative_scan(filtering_combine, e, axis_name="sp",
                                     identity=filtering_identity(nx, jnp.float64))
 
-@partial(shard_map, mesh=mesh, in_specs=(spec_s,), out_specs=spec_s)
+@partial(jax.shard_map, mesh=mesh, in_specs=(spec_s,), out_specs=spec_s)
 def sharded_suffix(e):
     return sharded_associative_scan(smoothing_combine, e, axis_name="sp",
                                     identity=smoothing_identity(nx, jnp.float64),
@@ -68,7 +67,7 @@ a = jnp.asarray(rng.uniform(0.5, 1.0, (n, d)))
 b = jnp.asarray(rng.standard_normal((n, d)))
 ref_h = linear_recurrence_scan(a, b)
 
-@partial(shard_map, mesh=mesh, in_specs=(P("sp"), P("sp")), out_specs=P("sp"))
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("sp"), P("sp")), out_specs=P("sp"))
 def sharded_rec(a, b):
     return linear_recurrence_scan(a, b, axis_name="sp")
 

@@ -8,7 +8,7 @@ import pytest
 from repro.core.types import FilteringElement, SmoothingElement
 from repro.kernels.kalman_combine import ops, ref
 from repro.kernels.kalman_combine.kalman_combine import (
-    filtering_combine_batched, smoothing_combine_batched,
+    block_rows, filtering_combine_batched, smoothing_combine_batched,
     _gauss_jordan_inverse)
 
 
@@ -64,6 +64,46 @@ def test_smoothing_combine_matches_oracle(B, nx, dtype):
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    **TOL[dtype])
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+@pytest.mark.parametrize("nx", [1, 2, 4, 5, 8])
+@pytest.mark.parametrize("kind", ["filtering", "smoothing"])
+def test_combine_at_dispatch_tile_matches_oracle(kind, nx, ragged):
+    """The kernels at the tile the dispatch picks for nx (`block_rows`,
+    the one that compiles for the chip), over two grid steps, and over a
+    batch that is not a multiple of the tile (padded last block)."""
+    make, fn, oracle = {
+        "filtering": (_rand_filtering, filtering_combine_batched,
+                      ref.filtering_combine_batched_ref),
+        "smoothing": (_rand_smoothing, smoothing_combine_batched,
+                      ref.smoothing_combine_batched_ref),
+    }[kind]
+    B = 2 * block_rows(nx) + (3 if ragged else 0)
+    rng = np.random.default_rng(B * 10 + nx)
+    ei = make(rng, B, nx, jnp.float32)
+    ej = make(rng, B, nx, jnp.float32)
+    got = fn(ei, ej, interpret=True)
+    want = oracle(ei, ej)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   **TOL[jnp.float32])
+
+
+def test_block_rows_shrinks_with_nx():
+    """Powers of two, at least one sublane tile (8), and never wider for
+    a larger nx."""
+    rows = [block_rows(nx) for nx in range(1, 17)]
+    assert all(r >= 8 and r & (r - 1) == 0 for r in rows)
+    assert rows == sorted(rows, reverse=True)
+
+
+def test_compiled_kernel_refuses_float64():
+    """Mosaic has no float64 lowering: the compiled path says so instead
+    of failing inside the compiler."""
+    e = _rand_smoothing(np.random.default_rng(0), 8, 2, jnp.float64)
+    with pytest.raises(ValueError, match="float32 only"):
+        smoothing_combine_batched(e, e, interpret=False)
 
 
 @pytest.mark.parametrize("n", [1, 2, 4, 6, 10])
@@ -151,19 +191,13 @@ def test_off_accelerator_pallas_falls_back_to_fused():
 
 
 def test_wrong_platform_backend_degrades_with_warning():
-    """backend="tpu"/"gpu" on a mismatched host resolves to None (fused
-    fallback) with a one-time warning; "interpret" is honored; unknown
-    names raise."""
-    import warnings
-
+    """backend="tpu"/"gpu" on a mismatched host raises, naming the
+    missing device (a fused run would pass a CPU run off as that
+    device's); "interpret" is honored; unknown names raise."""
     have = ops.kernel_backend()
     wrong = "tpu" if have != "tpu" else "gpu"
-    ops._warned.discard(f"pallas-wrong-platform-{wrong}")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert ops.resolve_backend(wrong) is None
-        assert ops.resolve_backend(wrong) is None
-    assert sum("cannot compile" in str(x.message) for x in w) == 1
+    with pytest.raises(RuntimeError, match=f"no {wrong} device"):
+        ops.resolve_backend(wrong)
     assert ops.resolve_backend("interpret") == "interpret"
     if have is not None:
         assert ops.resolve_backend(have) == have
@@ -208,3 +242,16 @@ def test_dispatch_is_trace_stable_across_scan_levels(monkeypatch, n,
     assert counts[expect] > 0
     assert counts[other] == 0, (
         f"dispatch flipped to {other} mid-scan: {counts}")
+
+
+def test_autotune_verdict_is_keyed_by_dtype(monkeypatch):
+    """A kernel verdict measured in float32 does not route a float64
+    trace of the same shape to the kernel (float32-only lowering)."""
+    from repro.kernels.kalman_combine import autotune as at
+
+    monkeypatch.setattr(at, "_cache", {})
+    at._cache[at.cache_key("spec", 4, 8, 2, jnp.float32)] = {
+        "choice": at.CHOICE_KERNEL}
+    assert at.decide("spec", 4, 8, 2, jnp.float32) == at.CHOICE_KERNEL
+    assert at.decide("spec", 4, 8, 2, jnp.float64) == at.CHOICE_FUSED
+    assert at.decide("spec", 4, 8, 2) == at.CHOICE_FUSED

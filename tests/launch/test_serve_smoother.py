@@ -107,3 +107,21 @@ def test_stream_serve_smoother_end_to_end():
     assert stats["mean_rmse"] < 1.0
     assert all(m is not None for m in stats["results"])
     assert stats["flush_reasons"]    # at least one flush actually fired
+
+
+def test_oneshot_reports_verdicts(served):
+    """The one-shot path reports a verdict per request, as the stream
+    does: every healthy lane is "ok"."""
+    *_, stats = served
+    assert stats["verdicts"] == {"ok": 5}
+
+
+def test_f64_serving_refused_on_tpu(monkeypatch):
+    """float64 serving cannot compile on a TPU; the server says so up
+    front instead of failing inside the first launch."""
+    from repro.launch import serve
+
+    monkeypatch.setattr(serve.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="float64 serving does not run"):
+        serve.serve_dtype(SmootherServeConfig(f64=True))
+    assert serve.serve_dtype(SmootherServeConfig(f64=False)) == jnp.float32

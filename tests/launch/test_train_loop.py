@@ -46,8 +46,9 @@ from repro.configs import get_config, reduced_config
 from repro.configs.base import ShapeConfig
 from repro.launch.steps import make_cell_plan
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = dataclasses.replace(reduced_config(get_config("deepseek-moe-16b")),
                           tp_size=2)
 for shape in (ShapeConfig("t", 64, 4, "train"),

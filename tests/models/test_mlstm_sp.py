@@ -8,6 +8,7 @@ from tests._subproc import check_snippet
 SNIPPET = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config, reduced_config
+from repro.launch.mesh import make_mesh
 from repro.models.xlstm import init_mlstm, mlstm_layer
 
 cfg = reduced_config(get_config("xlstm-350m"))
@@ -18,7 +19,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (B, T, cfg.d_model),
 
 ref, _ = mlstm_layer(params, x, cfg)          # no mesh: chunked form
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with mesh:
     got, _ = jax.jit(lambda p, xx: mlstm_layer(p, xx, cfg)[0])(params, x), None
 

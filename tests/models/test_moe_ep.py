@@ -7,6 +7,7 @@ from tests._subproc import check_snippet
 SNIPPET = r"""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config, reduced_config
+from repro.launch.mesh import make_mesh
 from repro.models.moe import init_moe, _moe_layer_global, moe_layer
 
 cfg = dataclasses.replace(
@@ -19,7 +20,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (B, T, cfg.d_model),
 
 ref, aux_ref = _moe_layer_global(params, x, cfg)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with mesh:
     got, aux_got = jax.jit(lambda p, xx: moe_layer(p, xx, cfg))(params, x)
 

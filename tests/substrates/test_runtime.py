@@ -58,14 +58,14 @@ COMPRESSED_PSUM_SNIPPET = r"""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import make_mesh
 from repro.optim import compressed_psum, init_compression
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 g = jnp.arange(8 * 32, dtype=jnp.float32).reshape(8, 32) / 17.0
 state = init_compression({"g": g[0]})
 
-@partial(shard_map, mesh=mesh, in_specs=(P("pod", None),),
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("pod", None),),
          out_specs=P("pod", None))
 def reduce_grads(gs):
     out, _ = compressed_psum({"g": gs[0]}, state, "pod")
