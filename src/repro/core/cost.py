@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from .linearization import (linearize_model_slr, linearize_model_slr_batched,
                             linearize_model_taylor,
                             linearize_model_taylor_batched)
+from .scopes import COST, scoped
 from .sigma_points import SigmaScheme, get_scheme
 from .types import Gaussian, LinearizedSSM, StateSpaceModel, bmv
 
@@ -41,6 +42,7 @@ def _half_quad(diff: jnp.ndarray, cov: jnp.ndarray) -> jnp.ndarray:
     return 0.5 * jnp.sum(z * z, axis=-1)
 
 
+@scoped(COST)
 def smoothing_cost(lin: LinearizedSSM, ys: jnp.ndarray, means: jnp.ndarray,
                    m0: jnp.ndarray, P0: jnp.ndarray) -> jnp.ndarray:
     """GN/MAP cost of a mean trajectory under a linearized model.

@@ -34,6 +34,7 @@ from .cost import gn_cost
 from .linearization import (linearize_model_slr, linearize_model_slr_batched,
                             linearize_model_taylor,
                             linearize_model_taylor_batched)
+from .scopes import ITERATE, LOGLIK, scoped
 from .sigma_points import SCHEMES, SigmaScheme, get_scheme
 from .types import (Gaussian, LinearizedSSM, StateSpaceModel, bmm, bmv,
                     mvn_logpdf)
@@ -453,6 +454,7 @@ def _adaptive_iterated(model: StateSpaceModel, ys: jnp.ndarray,
     return _pack_result(traj, hist, info, return_history, return_info)
 
 
+@scoped(ITERATE)
 def iterated_smoother(model: StateSpaceModel, ys: jnp.ndarray,
                       cfg: IteratedConfig = IteratedConfig(),
                       init: Optional[Gaussian] = None,
@@ -525,6 +527,7 @@ def _freeze_lanes(active: jnp.ndarray, new: Gaussian, old: Gaussian
     return jtm(sel, new, old)
 
 
+@scoped(ITERATE)
 def _iterated_smoother_batched(model: StateSpaceModel, ys: jnp.ndarray,
                                cfg: IteratedConfig = IteratedConfig(),
                                init: Optional[Gaussian] = None,
@@ -596,6 +599,7 @@ def _iterated_smoother_batched(model: StateSpaceModel, ys: jnp.ndarray,
     return _pack_result(traj, hist, info, return_history, return_info)
 
 
+@scoped(LOGLIK)
 def smoothed_log_likelihood(model: StateSpaceModel, ys: jnp.ndarray,
                             traj: Gaussian,
                             cfg: IteratedConfig = IteratedConfig(),

@@ -22,6 +22,7 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 
+from .scopes import LINEARIZE, scoped
 from .sigma_points import SigmaScheme
 from .types import Gaussian, LinearizedSSM, StateSpaceModel, broadcast_noise, symmetrize
 
@@ -57,6 +58,7 @@ def linearize_slr(phi: Callable, m: jnp.ndarray, P: jnp.ndarray,
     return F, c, Lam
 
 
+@scoped(LINEARIZE)
 def linearize_model_taylor(model: StateSpaceModel, traj_means: jnp.ndarray
                            ) -> LinearizedSSM:
     """Build the linearized SSM by Taylor expansion around a nominal
@@ -69,6 +71,7 @@ def linearize_model_taylor(model: StateSpaceModel, traj_means: jnp.ndarray
     return LinearizedSSM(F=Fs, c=cs, Qp=Q, H=Hs, d=ds, Rp=R)
 
 
+@scoped(LINEARIZE)
 def linearize_model_slr(model: StateSpaceModel, traj: Gaussian,
                         scheme: SigmaScheme, jitter: float = 0.0
                         ) -> LinearizedSSM:
@@ -122,6 +125,7 @@ def _unflat_rows(x: jnp.ndarray, B: int, n: int) -> jnp.ndarray:
     return x.reshape((B, n) + x.shape[1:])
 
 
+@scoped(LINEARIZE)
 def linearize_model_taylor_batched(model: StateSpaceModel,
                                    traj_means: jnp.ndarray) -> LinearizedSSM:
     """Taylor-linearize around ``B`` nominal trajectories ``[B, n+1, nx]``.
@@ -143,6 +147,7 @@ def linearize_model_taylor_batched(model: StateSpaceModel,
         Rp=broadcast_noise_batched(model.R, B, n))
 
 
+@scoped(LINEARIZE)
 def linearize_model_slr_batched(model: StateSpaceModel, traj: Gaussian,
                                 scheme: SigmaScheme, jitter: float = 0.0
                                 ) -> LinearizedSSM:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from . import scan as scan_lib
+from .scopes import COMBINE, FILTER, SMOOTH, scoped
 from .types import (FilteringElement, Gaussian, LinearizedSSM,
                     SmoothingElement, bcast_prior as _bcast_prior,
                     bmm as _mm, bmv as _mv, gauss_jordan_inverse,
@@ -39,6 +40,7 @@ def _T(A: jnp.ndarray) -> jnp.ndarray:
 # Associative combines (single pair; vmapped/tiled by the scan driver)
 # ---------------------------------------------------------------------------
 
+@scoped(COMBINE)
 def filtering_combine(ei: FilteringElement, ej: FilteringElement
                       ) -> FilteringElement:
     """Paper Eq. 15: ``a_i (x) a_j`` with ``i`` earlier in time than ``j``.
@@ -71,6 +73,7 @@ def filtering_combine(ei: FilteringElement, ej: FilteringElement
     return FilteringElement(A=A, b=b, C=C, eta=eta, J=J)
 
 
+@scoped(COMBINE)
 def smoothing_combine(ei: SmoothingElement, ej: SmoothingElement
                       ) -> SmoothingElement:
     """Paper Eq. 19: ``a_i (x) a_j`` with ``i`` earlier in time than ``j``."""
@@ -241,6 +244,7 @@ def smoothing_elements_batched(lin: LinearizedSSM, filtered: Gaussian
 # Drivers
 # ---------------------------------------------------------------------------
 
+@scoped(FILTER)
 def parallel_filter(lin: LinearizedSSM, ys: jnp.ndarray, m0: jnp.ndarray,
                     P0: jnp.ndarray, *, combine_impl: str = "jnp",
                     axis_name: str = None) -> Gaussian:
@@ -257,6 +261,7 @@ def parallel_filter(lin: LinearizedSSM, ys: jnp.ndarray, m0: jnp.ndarray,
     return Gaussian(mean=scanned.b, cov=scanned.C)
 
 
+@scoped(SMOOTH)
 def parallel_smoother(lin: LinearizedSSM, filtered: Gaussian, m0: jnp.ndarray,
                       P0: jnp.ndarray, *, combine_impl: str = "jnp",
                       axis_name: str = None) -> Gaussian:
@@ -299,6 +304,7 @@ def parallel_filter_smoother(lin: LinearizedSSM, ys: jnp.ndarray,
 # Batched drivers: B trajectories, one fused scan per Blelloch level
 # ---------------------------------------------------------------------------
 
+@scoped(FILTER)
 def parallel_filter_batched(lin: LinearizedSSM, ys: jnp.ndarray,
                             m0: jnp.ndarray, P0: jnp.ndarray, *,
                             combine_impl: str = "fused",
@@ -317,6 +323,7 @@ def parallel_filter_batched(lin: LinearizedSSM, ys: jnp.ndarray,
     return Gaussian(mean=scanned.b, cov=scanned.C)
 
 
+@scoped(SMOOTH)
 def parallel_smoother_batched(lin: LinearizedSSM, filtered: Gaussian,
                               m0: jnp.ndarray, P0: jnp.ndarray, *,
                               combine_impl: str = "fused",

@@ -11,10 +11,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .scopes import FILTER, SMOOTH, scoped
 from .types import (Gaussian, LinearizedSSM, bcast_prior as _bcast_prior,
                     mvn_logpdf, symmetrize)
 
 
+@scoped(FILTER)
 def kalman_filter(lin: LinearizedSSM, ys: jnp.ndarray, m0: jnp.ndarray,
                   P0: jnp.ndarray, return_loglik: bool = False):
     """Sequential (extended/SLR) Kalman filter.
@@ -52,6 +54,7 @@ def kalman_filter(lin: LinearizedSSM, ys: jnp.ndarray, m0: jnp.ndarray,
     return out
 
 
+@scoped(SMOOTH)
 def rts_smoother(lin: LinearizedSSM, filtered: Gaussian, m0: jnp.ndarray,
                  P0: jnp.ndarray) -> Gaussian:
     """Sequential Rauch-Tung-Striebel smoother.
@@ -101,6 +104,7 @@ def _time_major(tree):
     return jax.tree_util.tree_map(lambda x: jnp.swapaxes(x, 0, 1), tree)
 
 
+@scoped(FILTER)
 def kalman_filter_batched(lin: LinearizedSSM, ys: jnp.ndarray,
                           m0: jnp.ndarray, P0: jnp.ndarray,
                           return_loglik: bool = False):
@@ -138,6 +142,7 @@ def kalman_filter_batched(lin: LinearizedSSM, ys: jnp.ndarray,
     return out
 
 
+@scoped(SMOOTH)
 def rts_smoother_batched(lin: LinearizedSSM, filtered: Gaussian,
                          m0: jnp.ndarray, P0: jnp.ndarray) -> Gaussian:
     """Sequential RTS smoother over ``[B, n]`` lanes in one reverse scan."""

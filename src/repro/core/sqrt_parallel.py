@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
 from . import scan as scan_lib
+from .scopes import COMBINE, FILTER, SMOOTH, scoped
 from .types import (Gaussian, LinearizedSSM, bcast_prior as _bcast_prior,
                     symmetrize)
 
@@ -140,6 +141,7 @@ def sqrt_filtering_elements(lin: LinearizedSSM, ys, m0, P0
 # Combines
 # ---------------------------------------------------------------------------
 
+@scoped(COMBINE)
 def sqrt_filtering_combine(ei: SqrtFilteringElement,
                            ej: SqrtFilteringElement
                            ) -> SqrtFilteringElement:
@@ -169,6 +171,7 @@ def sqrt_filtering_combine(ei: SqrtFilteringElement,
     return SqrtFilteringElement(A=A, b=b, U=U, eta=eta, Z=Z)
 
 
+@scoped(COMBINE)
 def sqrt_smoothing_combine(ei: SqrtSmoothingElement,
                            ej: SqrtSmoothingElement) -> SqrtSmoothingElement:
     E = ei.E @ ej.E
@@ -194,6 +197,7 @@ def sqrt_smoothing_identity(nx: int, dtype=jnp.float32):
 # Drivers (mirror repro.core.parallel)
 # ---------------------------------------------------------------------------
 
+@scoped(FILTER)
 def sqrt_parallel_filter(lin: LinearizedSSM, ys, m0, P0, *,
                          axis_name=None) -> Gaussian:
     elems = sqrt_filtering_elements(lin, ys, m0, P0)
@@ -235,6 +239,7 @@ def sqrt_smoothing_elements(lin: LinearizedSSM, filtered: Gaussian
         lambda b, l: jnp.concatenate([b, l[None]], axis=0), body, last)
 
 
+@scoped(SMOOTH)
 def sqrt_parallel_smoother(lin: LinearizedSSM, filtered: Gaussian, m0, P0,
                            *, axis_name=None) -> Gaussian:
     elems = sqrt_smoothing_elements(lin, filtered)
@@ -285,6 +290,7 @@ def sqrt_filtering_elements_batched(lin: LinearizedSSM, ys, m0, P0
         lambda g, f: g.at[:, 0].set(f), generic, first)
 
 
+@scoped(FILTER)
 def sqrt_parallel_filter_batched(lin: LinearizedSSM, ys, m0, P0, *,
                                  axis_name=None) -> Gaussian:
     elems = sqrt_filtering_elements_batched(lin, ys, m0, P0)
@@ -316,6 +322,7 @@ def sqrt_smoothing_elements_batched(lin: LinearizedSSM, filtered: Gaussian
         lambda b, l: jnp.concatenate([b, l[:, None]], axis=1), body, last)
 
 
+@scoped(SMOOTH)
 def sqrt_parallel_smoother_batched(lin: LinearizedSSM, filtered: Gaussian,
                                    m0, P0, *, axis_name=None) -> Gaussian:
     B = filtered.mean.shape[0]

@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 # Shared batched-tiny-linalg primitives (also used by the plain-jnp fast
 # paths in repro.core): last-axis-reduce matmuls and the no-pivot
 # Gauss-Jordan elimination, both Mosaic-compatible.
+from repro.core.scopes import COMBINE, scoped
 from repro.core.types import bmm as _bmm, bmv as _bmv, eye32 as _eye32, \
     gauss_jordan_inverse as _gauss_jordan_inverse
 
@@ -73,6 +74,7 @@ def _filtering_kernel(Ai, bi, Ci, etai, Ji, Aj, bj, Cj, etaj, Jj,
     Ao[...], bo[...], Co[...], etao[...], Jo[...] = outs
 
 
+@scoped(COMBINE)
 def filtering_combine_batched_jnp(ei, ej):
     """Fused batched Eq. 15 combine in plain jnp — the CPU/GPU fast path.
 
@@ -103,6 +105,7 @@ def _smoothing_kernel(Ei, gi, Li, Ej, gj, Lj, Eo, go, Lo):
         Ei[...], gi[...], Li[...], Ej[...], gj[...], Lj[...])
 
 
+@scoped(COMBINE)
 def smoothing_combine_batched_jnp(ei, ej):
     """Fused batched Eq. 19 combine in plain jnp (see filtering twin)."""
     return type(ei)(*smoothing_combine_math(*ei, *ej))
@@ -151,6 +154,7 @@ def _block_specs(num_fields, nx, tb):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+@scoped(COMBINE)
 def filtering_combine_batched(ei, ej, *, interpret: bool,
                               tile: Optional[int] = None):
     """Fused Eq. 15 combine over batched elements (leading dim B).
@@ -174,11 +178,13 @@ def filtering_combine_batched(ei, ej, *, interpret: bool,
         out_specs=spec5,
         out_shape=out_shapes,
         interpret=interpret,
+        name="kalman_filtering_combine",
     )(*args)
     return type(ei)(*(o[:B] for o in outs))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
+@scoped(COMBINE)
 def smoothing_combine_batched(ei, ej, *, interpret: bool,
                               tile: Optional[int] = None):
     """Fused Eq. 19 combine over batched elements (leading dim B).
@@ -202,5 +208,6 @@ def smoothing_combine_batched(ei, ej, *, interpret: bool,
         out_specs=spec3,
         out_shape=out_shapes,
         interpret=interpret,
+        name="kalman_smoothing_combine",
     )(*args)
     return type(ei)(*(o[:B] for o in outs))

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import triton as plgpu
 
+from repro.core.scopes import COMBINE, scoped
+
 from . import ref as _ref
 from .kalman_combine import (_block_specs, _filtering_kernel,
                              _smoothing_kernel)
@@ -68,6 +70,7 @@ def _combine_call(kernel, num_fields, ei, ej, B, nx, tile, interpret,
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret",
                                              "num_warps", "num_stages"))
+@scoped(COMBINE)
 def filtering_combine_batched_triton(ei, ej, *, tile: int = _TILE,
                                      interpret: bool = False,
                                      num_warps: int = 4,
@@ -84,6 +87,7 @@ def filtering_combine_batched_triton(ei, ej, *, tile: int = _TILE,
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret",
                                              "num_warps", "num_stages"))
+@scoped(COMBINE)
 def smoothing_combine_batched_triton(ei, ej, *, tile: int = _TILE,
                                      interpret: bool = False,
                                      num_warps: int = 4,

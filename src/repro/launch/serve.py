@@ -132,6 +132,37 @@ def serve(serve_cfg: ServeConfig, emit=print) -> dict:
 
 R_PAD_SCALE = 1e8  # measurement-noise inflation on padded time steps
 
+#: Work counters of the smoother launches (DESIGN.md §6): every
+#: `SmootherServer.smooth_batch` adds its launch's counts to the server's
+#: ``counters``, and each serving call reports what its own launches added.
+#:   step_lanes_launched  b_pad x n_pad
+#:   step_lanes_real      the real tracks' lengths
+#:   lane_passes_run      real lanes x the loop's trip count (the most
+#:                        passes any lane of the launch ran)
+#:   lane_passes_active   real lanes' own passes (`LaneStatus.iterations`)
+LAUNCH_COUNTERS = ("step_lanes_launched", "step_lanes_real",
+                   "lane_passes_run", "lane_passes_active")
+
+
+def launch_counts(n_pad: int, b_pad: int, lengths: List[int],
+                  iterations: np.ndarray) -> Dict[str, int]:
+    """One launch's `LAUNCH_COUNTERS` from its real tracks' lengths and
+    its lanes' ``iterations`` (all ``b_pad``; the one copy of them to the
+    host is made here)."""
+    iterations = np.asarray(iterations)
+    real = len(lengths)
+    return {"step_lanes_launched": b_pad * n_pad,
+            "step_lanes_real": int(sum(lengths)),
+            "lane_passes_run": real * int(iterations.max()),
+            "lane_passes_active": int(iterations[:real].sum())}
+
+
+def _counts_since(servers, before: List[Dict[str, int]]) -> Dict[str, int]:
+    """What ``servers``' launches added to their counters since the
+    snapshots ``before`` (one per server, same order)."""
+    return {k: sum(s.counters[k] - b[k] for s, b in zip(servers, before))
+            for k in LAUNCH_COUNTERS}
+
 
 def _backend_choices() -> Dict[str, str]:
     """The autotuner's measured combine-backend verdicts so far, keyed
@@ -281,6 +312,8 @@ class SmootherServer:
         # Per-bucket executable signatures seen so far (compile-count
         # bookkeeping; jax.jit caches by shape, this mirrors its keys).
         self.signatures_seen = set()
+        # Work counters summed over every launch of this server.
+        self.counters = dict.fromkeys(LAUNCH_COUNTERS, 0)
 
     def _make_run(self, smoother):
         def run(ys, r_stack):
@@ -331,27 +364,45 @@ class SmootherServer:
         (True = finite posterior and not `LANE_DIVERGED`).
 
         ``lane`` selects the executable: ``"primary"`` is the server's
-        spec, ``"retry"`` the adaptive-damping bounded-retry spec."""
+        spec, ``"retry"`` the adaptive-damping bounded-retry spec.
+
+        The launch is one ``serve.launch`` profiler span (arguments
+        ``n_pad``, ``b_pad``, ``lanes``: the real ones, ``lane``) around
+        four: ``serve.pad`` (padding and the copy to the device),
+        ``serve.dispatch`` (the jitted call), ``serve.wait`` (until the
+        device is done) and ``serve.unpack`` (answers to the host, lane
+        health, `LAUNCH_COUNTERS`)."""
         from repro.core import LANE_DIVERGED
 
+        span = jax.profiler.TraceAnnotation
         smoother_run, icfg = ((self._run, self._icfg)
                               if lane == "primary"
                               else (self._retry_run,
                                     self._retry_smoother.config))
         self.signatures_seen.add(
             icfg.cache_key(n_pad, b_pad, self.model.nx))
-        ys, rs = self._pad_bucket(batch, n_pad, b_pad)
-        traj, info, ll_steps = smoother_run(ys, rs)
-        jax.block_until_ready(traj.mean)
-        means = [np.asarray(traj.mean[i, :len(y) + 1])
-                 for i, y in enumerate(batch)]
-        ll_steps = np.asarray(ll_steps)
-        logliks = [float(np.sum(ll_steps[i, :len(y)]))
-                   for i, y in enumerate(batch)]
-        codes = np.asarray(info.code)
-        health = [bool(codes[i] != LANE_DIVERGED)
-                  and bool(np.isfinite(m).all())
-                  for i, m in enumerate(means)]
+        with span("serve.launch", n_pad=n_pad, b_pad=b_pad,
+                  lanes=len(batch), lane=lane):
+            with span("serve.pad"):
+                ys, rs = self._pad_bucket(batch, n_pad, b_pad)
+            with span("serve.dispatch"):
+                traj, info, ll_steps = smoother_run(ys, rs)
+            with span("serve.wait"):
+                jax.block_until_ready(traj.mean)
+            with span("serve.unpack"):
+                means = [np.asarray(traj.mean[i, :len(y) + 1])
+                         for i, y in enumerate(batch)]
+                ll_steps = np.asarray(ll_steps)
+                logliks = [float(np.sum(ll_steps[i, :len(y)]))
+                           for i, y in enumerate(batch)]
+                codes = np.asarray(info.code)
+                health = [bool(codes[i] != LANE_DIVERGED)
+                          and bool(np.isfinite(m).all())
+                          for i, m in enumerate(means)]
+                for k, v in launch_counts(n_pad, b_pad,
+                                          [len(y) for y in batch],
+                                          info.iterations).items():
+                    self.counters[k] += v
         return means, info, logliks, health
 
     def warmup(self, n_pads, b_pads, estimator: ComputeEstimator = None):
@@ -375,8 +426,9 @@ class SmootherServer:
                 # on hosts with no compiled lowering it records "fused"
                 # without timing anything).
                 if self.spec.backend == "auto":
-                    self._smoother.autotune(b_pad, n_pad, self.model.nx,
-                                            self.model.m0.dtype)
+                    with jax.profiler.TraceAnnotation("serve.autotune"):
+                        self._smoother.autotune(b_pad, n_pad, self.model.nx,
+                                                self.model.m0.dtype)
                 key = self._icfg.cache_key(n_pad, b_pad, self.model.nx)
                 if key not in self.signatures_seen:
                     self.smooth_batch(dummy, n_pad, b_pad)  # compile
@@ -490,7 +542,7 @@ class SmootherServer:
         logliks: List[Optional[float]] = [None] * len(requests)
         verdicts: Dict[str, int] = defaultdict(int)
         launches = 0
-        iters_total = 0
+        before = [dict(self.counters)]
         t0 = time.perf_counter()
         for sig in sorted(buckets):
             n_pad = sig[2]
@@ -501,7 +553,7 @@ class SmootherServer:
                 # (autobatch.pad_width): one bounded executable-cache
                 # contract whether requests arrive one-shot or queued.
                 b_pad = pad_width(len(chunk), self.cfg.max_batch)
-                means, info, lls, health = self.smooth_batch(
+                means, _, lls, health = self.smooth_batch(
                     [requests[i] for i in chunk], n_pad, b_pad)
                 for i, m, ll, ok in zip(chunk, means, lls, health):
                     results[i] = m
@@ -509,9 +561,8 @@ class SmootherServer:
                     # No retry lane here: a diverged lane is reported.
                     verdicts[VERDICT_OK if ok else VERDICT_DIVERGED] += 1
                 launches += 1
-                iters_total += int(np.sum(np.asarray(
-                    info.iterations)[:len(chunk)]))
         dt = time.perf_counter() - t0
+        counts = _counts_since([self], before)
         stats = {
             "results": results,
             "logliks": logliks,
@@ -519,9 +570,11 @@ class SmootherServer:
             "launches": launches,
             "verdicts": dict(verdicts),
             "compiles": len(self.signatures_seen),
-            "mean_iterations": iters_total / max(len(requests), 1),
+            "mean_iterations": (counts["lane_passes_active"]
+                                / max(len(requests), 1)),
             "wall_s": dt,
             "traj_per_s": len(requests) / dt,
+            **counts,
         }
         emit(f"[serve/smoother] {len(requests)} requests in {launches} "
              f"bucket launches, {dt:.2f}s ({stats['traj_per_s']:.1f} traj/s,"
@@ -578,15 +631,13 @@ class SmootherServer:
 
         results: List[Optional[np.ndarray]] = [None] * len(requests)
         logliks: List[Optional[float]] = [None] * len(requests)
-        iters_total = 0
+        before = [dict(self.counters)]
 
         def execute(fl):
-            dt, outcomes, store, iters = self.run_flush(fl)
+            dt, outcomes, store, _ = self.run_flush(fl)
             for rid, (m, ll) in store.items():
                 results[rid] = m
                 logliks[rid] = ll
-            nonlocal iters_total
-            iters_total += iters
             return dt, outcomes
 
         exec_fn = execute
@@ -597,17 +648,20 @@ class SmootherServer:
         service = run_service(qreqs, exec_fn, policy, estimator,
                               retry=self.retry_request,
                               watchdog=StepWatchdog())
+        counts = _counts_since([self], before)
         stats = summarize_service(service)
         stats.update({
             "results": results,
             "logliks": logliks,
-            "mean_iterations": iters_total / max(len(requests), 1),
+            "mean_iterations": (counts["lane_passes_active"]
+                                / max(len(requests), 1)),
             "compiles": len(self.signatures_seen),
             "records": service["records"],
             "launch_log": service["launches"],
             "backend_choices": _backend_choices(),
             "chaos": (injector.summary() if injector is not None
                       else None),
+            **counts,
         })
         emit(f"[serve/smoother/{policy.kind}] {stats['requests']} requests "
              f"in {stats['launches']} launches "
@@ -788,17 +842,16 @@ class MultiTenantServer:
 
         results: List[Optional[np.ndarray]] = [None] * len(requests)
         logliks: List[Optional[float]] = [None] * len(requests)
-        iters_total = 0
+        servers = list(self.servers.values())
+        before = [dict(s.counters) for s in servers]
 
         def execute(fl):
             model_id, method, _, _ = fl.signature
             server = self._by_model[(model_id, method)]
-            dt, outcomes, store, iters = server.run_flush(fl)
+            dt, outcomes, store, _ = server.run_flush(fl)
             for rid, (m, ll) in store.items():
                 results[rid] = m
                 logliks[rid] = ll
-            nonlocal iters_total
-            iters_total += iters
             return dt, outcomes
 
         exec_fn = execute
@@ -809,18 +862,20 @@ class MultiTenantServer:
         service = run_service(qreqs, exec_fn, policy, estimator,
                               retry=self.retry_request,
                               watchdog=StepWatchdog())
+        counts = _counts_since(servers, before)
         stats = summarize_service(service)
         stats.update({
             "results": results,
             "logliks": logliks,
-            "mean_iterations": iters_total / max(len(requests), 1),
-            "compiles": sum(len(s.signatures_seen)
-                            for s in self.servers.values()),
+            "mean_iterations": (counts["lane_passes_active"]
+                                / max(len(requests), 1)),
+            "compiles": sum(len(s.signatures_seen) for s in servers),
             "records": service["records"],
             "launch_log": service["launches"],
             "backend_choices": _backend_choices(),
             "chaos": (injector.summary() if injector is not None
                       else None),
+            **counts,
         })
         emit(f"[serve/smoother/mt/{policy.kind}] {stats['requests']} "
              f"requests, {len(self.servers)} tenants, "
