@@ -31,15 +31,8 @@ from .linearization import (linearize_model_slr, linearize_model_slr_batched,
                             linearize_model_taylor_batched)
 from .scopes import COST, scoped
 from .sigma_points import SigmaScheme, get_scheme
-from .types import Gaussian, LinearizedSSM, StateSpaceModel, bmv
-
-
-def _half_quad(diff: jnp.ndarray, cov: jnp.ndarray) -> jnp.ndarray:
-    """``1/2 diff^T cov^-1 diff`` over the last axis, batched over the
-    rest (Cholesky solve, same idiom as `types.mvn_logpdf`)."""
-    chol = jnp.linalg.cholesky(cov)
-    z = jnp.linalg.solve(chol, diff[..., None])[..., 0]
-    return 0.5 * jnp.sum(z * z, axis=-1)
+from .types import (Gaussian, LinearizedSSM, StateSpaceModel, bmv,
+                    chol_half_quad)
 
 
 @scoped(COST)
@@ -57,9 +50,9 @@ def smoothing_cost(lin: LinearizedSSM, ys: jnp.ndarray, means: jnp.ndarray,
     prior_res = means[..., 0, :] - m0
     trans_res = nxt - bmv(lin.F, prev) - lin.c
     meas_res = ys - bmv(lin.H, nxt) - lin.d
-    return (_half_quad(prior_res, P0)
-            + jnp.sum(_half_quad(trans_res, lin.Qp), axis=-1)
-            + jnp.sum(_half_quad(meas_res, lin.Rp), axis=-1))
+    return (chol_half_quad(prior_res, P0)[0]
+            + jnp.sum(chol_half_quad(trans_res, lin.Qp)[0], axis=-1)
+            + jnp.sum(chol_half_quad(meas_res, lin.Rp)[0], axis=-1))
 
 
 def gn_cost(model: StateSpaceModel, ys: jnp.ndarray, traj: Gaussian,

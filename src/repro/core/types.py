@@ -136,6 +136,44 @@ def gauss_jordan_inverse(W: jnp.ndarray) -> jnp.ndarray:
     return aug[..., :, n:]
 
 
+def chol_half_quad(diff: jnp.ndarray, cov: jnp.ndarray
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(1/2 diff^T cov^-1 diff, log det cov)`` for SPD ``cov [..., d, d]``
+    and ``diff [..., d]``, batched (and broadcast) over the leading axes.
+
+    Cholesky-Banachiewicz then forward substitution ``L z = diff``,
+    unrolled over the static ``d``: every factor entry ``L[i][j]`` and
+    every ``z[i]`` is an array of the batch shape, so the work is
+    elementwise arithmetic with the batch (time) axes on the vector lanes
+    and no per-matrix LAPACK-style call, as in `gauss_jordan_inverse`.
+    Like ``jnp.linalg.cholesky``: no pivoting, the input is symmetrized,
+    and a pivot that is not positive gives NaN (a non-PD covariance makes
+    both results NaN).
+    """
+    d = diff.shape[-1]
+    L = [[None] * d for _ in range(d)]
+    inv_diag, z = [], []
+    logdet = quad = 0.0
+    for i in range(d):
+        for j in range(i + 1):
+            s = (cov[..., i, i] if i == j
+                 else 0.5 * (cov[..., i, j] + cov[..., j, i]))
+            for k in range(j):
+                s = s - L[i][k] * L[j][k]
+            if i == j:
+                pivot = jnp.sqrt(jnp.where(s > 0, s, jnp.nan))
+                inv_diag.append(1.0 / pivot)
+                logdet = logdet + jnp.log(pivot)
+            else:
+                L[i][j] = s * inv_diag[j]
+        r = diff[..., i]
+        for k in range(i):
+            r = r - L[i][k] * z[k]
+        z.append(r * inv_diag[i])
+        quad = quad + z[i] * z[i]
+    return 0.5 * quad, jnp.broadcast_to(2.0 * logdet, quad.shape)
+
+
 def bmm(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """Batched tiny matmul ``[..., n, m] @ [..., m, p]`` as broadcast-mul-
     reduce over the *last* (contiguous/lane) axis: C[i,k] = sum_j A[i,j] *
@@ -163,9 +201,5 @@ def bcast_prior(x: jnp.ndarray, B: int, ndim: int) -> jnp.ndarray:
 def mvn_logpdf(x: jnp.ndarray, mean: jnp.ndarray, cov: jnp.ndarray) -> jnp.ndarray:
     """Log-density of ``N(x; mean, cov)`` (used for data log-likelihood)."""
     d = x.shape[-1]
-    chol = jnp.linalg.cholesky(cov)
-    diff = x - mean
-    z = jnp.linalg.solve(chol, diff[..., None])[..., 0]
-    quad = jnp.sum(z * z, axis=-1)
-    logdet = 2.0 * jnp.sum(jnp.log(jnp.diagonal(chol, axis1=-2, axis2=-1)), axis=-1)
-    return -0.5 * (quad + logdet + d * jnp.log(2.0 * jnp.pi))
+    half_quad, logdet = chol_half_quad(x - mean, cov)
+    return -0.5 * (2.0 * half_quad + logdet + d * jnp.log(2.0 * jnp.pi))
