@@ -22,6 +22,10 @@ COST = "smoother.cost"            # `cost.smoothing_cost`
 LOGLIK = "smoother.loglik"        # `iterated.smoothed_log_likelihood`
 
 STAGES = (ITERATE, LINEARIZE, FILTER, SMOOTH, COMBINE, COST, LOGLIK)
+#: Inside any stage: the blocked inverses and Cholesky factors of the
+#: wide algebra (`types.UNROLL_MAX`). Not a stage of its own, and absent
+#: where every dimension unrolls.
+FACTOR = "smoother.factor"
 
 
 def scoped(name: str):

@@ -22,11 +22,16 @@ Contract (DESIGN.md §12):
     dtype)`` hit the cache and do not re-measure;
   * the measurement runs in the call site's dtype, and the dtype is in
     the key: a float32 verdict never routes a float64 trace to a kernel
-    that has only a float32 lowering.
+    that has only a float32 lowering;
+  * a kernel that the compiler refuses at the shape (at nx = 40 Mosaic
+    has no lowering for the blocked inverse's dynamic slices) is not a
+    candidate: the entry says "fused", keeps the refusal under
+    ``kernel_error``, and a warning says so.
 """
 from __future__ import annotations
 
 import time
+import warnings
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -145,9 +150,25 @@ def autotune(spec_id: str, B: int, T: int, nx: int, dtype) -> dict:
         # the real dispatch target at this element count
         __import__("repro.core.parallel", fromlist=["filtering_combine"])
         .filtering_combine, total_elems=int(B) * int(T), backend=backend)
-    fused = _k.filtering_combine_batched_jnp
-    kernel_us = _time_op(jax.jit(kernel_op), ei, ej)
-    fused_us = _time_op(jax.jit(fused), ei, ej)
+    fused = jax.jit(_k.filtering_combine_batched_jnp)
+    try:
+        kernel = jax.jit(kernel_op).lower(ei, ej).compile()
+    except Exception as e:  # noqa: BLE001 — whatever the compiler refuses
+        # Not a candidate at this shape (Mosaic has no lowering for an
+        # op of the kernel body, or the tile does not fit VMEM): the
+        # fused twin runs, and the refusal is kept and said.
+        error = f"{type(e).__name__}: {str(e).splitlines()[0][:300]}"
+        warnings.warn(
+            f"the {backend} combine kernel does not compile at B={B}, "
+            f"T={T}, nx={nx}, {jnp.dtype(dtype).name}; the fused combine "
+            f"runs there ({error})", RuntimeWarning, stacklevel=2)
+        entry = {"choice": CHOICE_FUSED, "backend": backend,
+                 "kernel_us": None, "fused_us": _time_op(fused, ei, ej),
+                 "kernel_error": error}
+        _cache[key] = entry
+        return entry
+    kernel_us = _time_op(kernel, ei, ej)
+    fused_us = _time_op(fused, ei, ej)
     choice = CHOICE_KERNEL if kernel_us < fused_us else CHOICE_FUSED
     entry = {"choice": choice, "backend": backend,
              "kernel_us": kernel_us, "fused_us": fused_us}

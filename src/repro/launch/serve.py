@@ -140,21 +140,38 @@ R_PAD_SCALE = 1e8  # measurement-noise inflation on padded time steps
 #:   lane_passes_run      real lanes x the loop's trip count (the most
 #:                        passes any lane of the launch ran)
 #:   lane_passes_active   real lanes' own passes (`LaneStatus.iterations`)
+#:   wide_launches        launches whose element algebra ran its wide
+#:                        lowering (`launch_algebra`)
 LAUNCH_COUNTERS = ("step_lanes_launched", "step_lanes_real",
-                   "lane_passes_run", "lane_passes_active")
+                   "lane_passes_run", "lane_passes_active", "wide_launches")
 
 
 def launch_counts(n_pad: int, b_pad: int, lengths: List[int],
-                  iterations: np.ndarray) -> Dict[str, int]:
-    """One launch's `LAUNCH_COUNTERS` from its real tracks' lengths and
-    its lanes' ``iterations`` (all ``b_pad``; the one copy of them to the
-    host is made here)."""
+                  iterations: np.ndarray, wide: bool = False
+                  ) -> Dict[str, int]:
+    """One launch's `LAUNCH_COUNTERS` from its real tracks' lengths, its
+    lanes' ``iterations`` (all ``b_pad``; the one copy of them to the host
+    is made here) and whether its algebra ran wide."""
     iterations = np.asarray(iterations)
     real = len(lengths)
     return {"step_lanes_launched": b_pad * n_pad,
             "step_lanes_real": int(sum(lengths)),
             "lane_passes_run": real * int(iterations.max()),
-            "lane_passes_active": int(iterations[:real].sum())}
+            "lane_passes_active": int(iterations[:real].sum()),
+            "wide_launches": int(wide)}
+
+
+def launch_algebra(icfg, nx: int, ny: int) -> Tuple[int, str]:
+    """``(m, lowering)`` of a launch under ``icfg``: the rows of a step's
+    measurement (``ny``, or ``ny + nx`` where Levenberg-Marquardt damping
+    adds the pseudo-measurement of the previous iterate), and the element
+    algebra those sizes run (`repro.core.types.lowering`: "unrolled" or
+    "wide")."""
+    from repro.core.types import lowering
+
+    damped = icfg.damping == "adaptive" or icfg.lm_lambda > 0.0
+    m = ny + nx if damped else ny
+    return m, lowering(nx, m)
 
 
 def _counts_since(servers, before: List[Dict[str, int]]) -> Dict[str, int]:
@@ -367,8 +384,9 @@ class SmootherServer:
         spec, ``"retry"`` the adaptive-damping bounded-retry spec.
 
         The launch is one ``serve.launch`` profiler span (arguments
-        ``n_pad``, ``b_pad``, ``lanes``: the real ones, ``lane``) around
-        four: ``serve.pad`` (padding and the copy to the device),
+        ``n_pad``, ``b_pad``, ``lanes``: the real ones, ``lane``, and
+        ``nx``, ``m`` and ``algebra`` as `launch_algebra` gives them)
+        around four: ``serve.pad`` (padding and the copy to the device),
         ``serve.dispatch`` (the jitted call), ``serve.wait`` (until the
         device is done) and ``serve.unpack`` (answers to the host, lane
         health, `LAUNCH_COUNTERS`)."""
@@ -381,8 +399,10 @@ class SmootherServer:
                                     self._retry_smoother.config))
         self.signatures_seen.add(
             icfg.cache_key(n_pad, b_pad, self.model.nx))
+        nx = self.model.nx
+        m, algebra = launch_algebra(icfg, nx, self.model.ny)
         with span("serve.launch", n_pad=n_pad, b_pad=b_pad,
-                  lanes=len(batch), lane=lane):
+                  lanes=len(batch), lane=lane, nx=nx, m=m, algebra=algebra):
             with span("serve.pad"):
                 ys, rs = self._pad_bucket(batch, n_pad, b_pad)
             with span("serve.dispatch"):
@@ -401,7 +421,8 @@ class SmootherServer:
                           for i, m in enumerate(means)]
                 for k, v in launch_counts(n_pad, b_pad,
                                           [len(y) for y in batch],
-                                          info.iterations).items():
+                                          info.iterations,
+                                          wide=algebra == "wide").items():
                     self.counters[k] += v
         return means, info, logliks, health
 

@@ -7,6 +7,8 @@ EXPERIMENTS.md scenario table):
   * ``bearings_only``          — CV target, passive bearings (nx=4, ekf)
   * ``pendulum``               — sin(theta) observation (nx=2, slr)
   * ``lorenz96``               — chaotic ring, partial obs (nx=8, ekf)
+  * ``lorenz96_40``            — the same ring at the data-assimilation
+                                 standard, every site observed (nx=40, ekf)
   * ``stochastic_volatility``  — AR(1) log-vol, exp obs (nx=1, slr)
   * ``population``             — logistic growth, exp obs (nx=1, slr)
 

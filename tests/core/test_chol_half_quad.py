@@ -4,7 +4,8 @@ Gauss-Newton cost and `mvn_logpdf` evaluate per element.
 Contract under test:
   * ``(1/2 d^T S^-1 d, log det S)`` agrees with a Cholesky factor plus a
     triangular solve (``jnp.linalg`` / ``jax.scipy``) in float64 and
-    float32: at every size from 1 to 17 (one path unrolls at every ``d``),
+    float32: at every size from 1 to 17 (unrolled up to
+    ``types.UNROLL_MAX``, blocked at 17),
     per element, over ``[B, n]`` stacks, with a shared
     covariance broadcast to the batch, with serving's 1e8-inflated padded
     steps, and on ill-conditioned covariances like the coordinated-turn

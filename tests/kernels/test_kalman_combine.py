@@ -255,3 +255,26 @@ def test_autotune_verdict_is_keyed_by_dtype(monkeypatch):
     assert at.decide("spec", 4, 8, 2, jnp.float32) == at.CHOICE_KERNEL
     assert at.decide("spec", 4, 8, 2, jnp.float64) == at.CHOICE_FUSED
     assert at.decide("spec", 4, 8, 2) == at.CHOICE_FUSED
+
+
+def test_autotune_records_a_kernel_the_compiler_refuses(monkeypatch):
+    """At a shape where the kernel does not compile (on a TPU: nx = 40,
+    whose blocked inverse Mosaic cannot lower), set-up goes on: the entry
+    says "fused", keeps the refusal, and a warning names it."""
+    from repro.kernels.kalman_combine import autotune as at
+
+    def refused(ei, ej):
+        raise NotImplementedError("Unimplemented primitive in Pallas TPU "
+                                  "lowering: dynamic_slice")
+
+    monkeypatch.setattr(at, "_cache", {})
+    monkeypatch.setattr(at._ops, "kernel_backend", lambda: "tpu")
+    monkeypatch.setattr(at._ops, "batched_combine_for",
+                        lambda *a, **k: refused)
+    with pytest.warns(RuntimeWarning, match="does not compile at B=4, T=8, "
+                      "nx=3.*dynamic_slice"):
+        entry = at.autotune("spec", 4, 8, 3, jnp.float32)
+    assert entry["choice"] == at.CHOICE_FUSED
+    assert entry["kernel_us"] is None and entry["fused_us"] > 0
+    assert entry["kernel_error"].startswith("NotImplementedError")
+    assert at.decide("spec", 4, 8, 3, jnp.float32) == at.CHOICE_FUSED

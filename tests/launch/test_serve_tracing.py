@@ -1,5 +1,6 @@
 """Instrumentation of the smoother service: named scopes on every stage,
-profiler spans around each launch, and the launches' work counters.
+profiler spans around each launch (with the element algebra each ran),
+and the launches' work counters.
 
 Scopes change HLO metadata only, so a server traced without them must
 give bit-identical answers; the counters are exact and hand-counted.
@@ -19,7 +20,8 @@ from repro.data import CoordinatedTurnConfig, make_coordinated_turn_model, \
     simulate_trajectory
 from repro.launch.autobatch import pad_width
 from repro.launch.serve import (LAUNCH_COUNTERS, SmootherServeConfig,
-                                SmootherServer, launch_counts)
+                                SmootherServer, launch_algebra, launch_counts)
+from repro.scenarios import get_scenario
 
 LENGTHS = [16, 9, 16]   # one width-4 launch at n_pad 16: 3 real lanes
 
@@ -73,17 +75,38 @@ def test_served_run_names_every_stage(model, requests):
     assert found == set(STAGES)
 
 
-@pytest.mark.parametrize("n_pad,b_pad,lengths,iterations,want", [
+@pytest.mark.parametrize("n_pad,b_pad,lengths,iterations,wide,want", [
     # Every lane runs the whole budget: nothing idle.
-    (512, 128, [512] * 100, [10] * 128, (65536, 51200, 1000, 1000)),
+    (512, 128, [512] * 100, [10] * 128, False,
+     (65536, 51200, 1000, 1000, 0)),
     # Lanes stop at 5, 6 and 10 passes; the padded lane copies lane 0.
-    (16, 4, [16, 9, 16], [5, 6, 10, 5], (64, 41, 30, 21)),
+    (16, 4, [16, 9, 16], [5, 6, 10, 5], False, (64, 41, 30, 21, 0)),
     # One real lane of a width-1 launch, time-padded 9 -> 16.
-    (16, 1, [9], [3], (16, 9, 3, 3)),
-], ids=["full_budget", "ragged_passes", "one_lane"])
-def test_launch_counts_by_hand(n_pad, b_pad, lengths, iterations, want):
-    got = launch_counts(n_pad, b_pad, lengths, np.asarray(iterations))
+    (16, 1, [9], [3], False, (16, 9, 3, 3, 0)),
+    # The Lorenz-96 fleet job: 128 windows of 64 steps, the wide algebra.
+    (64, 128, [64] * 128, [20] * 100 + [17] * 28, True,
+     (8192, 8192, 2560, 2476, 1)),
+], ids=["full_budget", "ragged_passes", "one_lane", "wide_algebra"])
+def test_launch_counts_by_hand(n_pad, b_pad, lengths, iterations, wide,
+                               want):
+    got = launch_counts(n_pad, b_pad, lengths, np.asarray(iterations),
+                        wide=wide)
     assert tuple(got[k] for k in LAUNCH_COUNTERS) == want
+
+
+@pytest.mark.parametrize("scenario,damping,lm_lambda,want", [
+    ("coordinated_turn", "adaptive", 1.0, (5, 7, "unrolled")),
+    ("coordinated_turn", "fixed", 0.0, (5, 2, "unrolled")),
+    ("lorenz96_40", "adaptive", 1.0, (40, 80, "wide")),
+    ("lorenz96_40", "fixed", 0.0, (40, 40, "wide")),
+])
+def test_launch_algebra_from_the_shapes(scenario, damping, lm_lambda, want):
+    """Damping adds the previous iterate's pseudo-measurement: ny + nx
+    rows a step; the lowering follows the widest size."""
+    sc = get_scenario(scenario)
+    icfg = sc.default_spec(damping=damping, lm_lambda=lm_lambda) \
+        .iterated_config()
+    assert (sc.nx,) + launch_algebra(icfg, sc.nx, sc.ny) == want
 
 
 def test_counters_follow_lane_status(model, requests):
@@ -96,7 +119,7 @@ def test_counters_follow_lane_status(model, requests):
     assert server.counters == {
         "step_lanes_launched": 4 * 16, "step_lanes_real": 41,
         "lane_passes_run": 3 * int(iters.max()),
-        "lane_passes_active": int(iters[:3].sum())}
+        "lane_passes_active": int(iters[:3].sum()), "wide_launches": 0}
 
 
 def _stub_run(nx):
@@ -138,7 +161,8 @@ def test_stream_stats_count_only_the_stream(model, requests):
     stream = server.serve_stream(requests, np.zeros(len(requests)),
                                  emit=lambda *_: None)
     for k in LAUNCH_COUNTERS:   # warmup launches are not the stream's
-        assert stream[k] == oneshot[k] > 0
+        assert stream[k] == oneshot[k]
+        assert stream[k] > 0 or k == "wide_launches"   # nx = 5 unrolls
     assert stream["mean_iterations"] == oneshot["mean_iterations"]
 
 
@@ -167,6 +191,7 @@ def test_launch_spans_nest(model, requests, tmp_path):
     _, lo, hi, args = launches[0]
     assert (args["n_pad"], args["b_pad"], args["lanes"], args["lane"]) \
         == (16, 4, 3, "primary")
+    assert (args["nx"], args["m"], args["algebra"]) == (5, 7, "unrolled")
     children = sorted((e for e in events if e[0] != "serve.launch"),
                       key=lambda e: e[1])
     assert [e[0] for e in children] == ["serve.pad", "serve.dispatch",
@@ -185,3 +210,28 @@ def test_warmup_autotune_span(model, tmp_path):
     names = [e[0] for e in _host_events(tmp_path)]
     assert names.count("serve.autotune") == 1
     assert names.count("serve.launch") == 1   # the compile launch
+
+
+def test_wide_launch_span_and_counter(tmp_path):
+    """A Lorenz-96 (40 sites) launch under the benchmark's spec: its span
+    says 40 states, 80 rows a step and the wide algebra, and the stats
+    count it (the launch itself stubbed: shapes only)."""
+    sc = get_scenario("lorenz96_40")
+    model = sc.make_model(jnp.float32)
+    server = SmootherServer(
+        model, SmootherServeConfig(max_batch=4, n_iter=20, tol=1e-4),
+        spec=sc.default_spec(n_iter=20, tol=1e-4, damping="adaptive",
+                             lm_lambda=1.0))
+    server._run = _stub_run(model.nx)
+    requests = [np.zeros((n, model.ny)) for n in (16, 16, 9)]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        stats = server.serve_requests(requests, emit=lambda *_: None)
+    finally:
+        jax.profiler.stop_trace()
+    assert stats["launches"] == stats["wide_launches"] == 1
+    launches = [e for e in _host_events(tmp_path) if e[0] == "serve.launch"]
+    assert len(launches) == 1
+    args = launches[0][3]
+    assert (args["nx"], args["m"], args["algebra"], args["lanes"]) \
+        == (40, 80, "wide", 3)
