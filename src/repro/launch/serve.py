@@ -150,8 +150,8 @@ def launch_counts(n_pad: int, b_pad: int, lengths: List[int],
                   iterations: np.ndarray, wide: bool = False
                   ) -> Dict[str, int]:
     """One launch's `LAUNCH_COUNTERS` from its real tracks' lengths, its
-    lanes' ``iterations`` (all ``b_pad``; the one copy of them to the host
-    is made here) and whether its algebra ran wide."""
+    lanes' ``iterations`` (all ``b_pad``, on the host) and whether its
+    algebra ran wide."""
     iterations = np.asarray(iterations)
     real = len(lengths)
     return {"step_lanes_launched": b_pad * n_pad,
@@ -375,7 +375,8 @@ class SmootherServer:
     def smooth_batch(self, batch: List[np.ndarray], n_pad: int, b_pad: int,
                      lane: str = "primary"):
         """Run one padded bucket launch; returns per-request trajectories
-        (list of ``[n_i + 1, nx]`` means), the per-lane iteration info,
+        (list of ``[n_i + 1, nx]`` means), the per-lane iteration info
+        (a `LaneStatus` of numpy arrays, all ``b_pad`` lanes),
         per-request smoothed log-likelihood fit scores (real steps only —
         padded-step terms are masked out), and per-request lane health
         (True = finite posterior and not `LANE_DIVERGED`).
@@ -383,13 +384,21 @@ class SmootherServer:
         ``lane`` selects the executable: ``"primary"`` is the server's
         spec, ``"retry"`` the adaptive-damping bounded-retry spec.
 
+        The answers reach the host in one ``jax.device_get`` of the whole
+        launch's means, per-step log-likelihoods and `LaneStatus` (never
+        the covariances, which no caller returns); each request's slice
+        is then cut in numpy: indexing the device array per request would
+        run (and first compile) a slice program and a blocking copy per
+        (lane, track length).
+
         The launch is one ``serve.launch`` profiler span (arguments
         ``n_pad``, ``b_pad``, ``lanes``: the real ones, ``lane``, and
         ``nx``, ``m`` and ``algebra`` as `launch_algebra` gives them)
         around four: ``serve.pad`` (padding and the copy to the device),
         ``serve.dispatch`` (the jitted call), ``serve.wait`` (until the
         device is done) and ``serve.unpack`` (answers to the host, lane
-        health, `LAUNCH_COUNTERS`)."""
+        health, `LAUNCH_COUNTERS`; arguments ``fetches``, the
+        device-to-host copies made, and ``bytes``, what they moved)."""
         from repro.core import LANE_DIVERGED
 
         span = jax.profiler.TraceAnnotation
@@ -409,18 +418,18 @@ class SmootherServer:
                 traj, info, ll_steps = smoother_run(ys, rs)
             with span("serve.wait"):
                 jax.block_until_ready(traj.mean)
-            with span("serve.unpack"):
-                means = [np.asarray(traj.mean[i, :len(y) + 1])
-                         for i, y in enumerate(batch)]
-                ll_steps = np.asarray(ll_steps)
-                logliks = [float(np.sum(ll_steps[i, :len(y)]))
-                           for i, y in enumerate(batch)]
-                codes = np.asarray(info.code)
-                health = [bool(codes[i] != LANE_DIVERGED)
+            fetch = (traj.mean, ll_steps, info)
+            nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(fetch))
+            with span("serve.unpack", fetches=1, bytes=nbytes):
+                mean, ll_steps, info = jax.device_get(fetch)
+                lengths = [len(y) for y in batch]
+                means = [mean[i, :n + 1] for i, n in enumerate(lengths)]
+                logliks = [float(np.sum(ll_steps[i, :n]))
+                           for i, n in enumerate(lengths)]
+                health = [bool(info.code[i] != LANE_DIVERGED)
                           and bool(np.isfinite(m).all())
                           for i, m in enumerate(means)]
-                for k, v in launch_counts(n_pad, b_pad,
-                                          [len(y) for y in batch],
+                for k, v in launch_counts(n_pad, b_pad, lengths,
                                           info.iterations,
                                           wide=algebra == "wide").items():
                     self.counters[k] += v
@@ -462,7 +471,7 @@ class SmootherServer:
                     # upper-bounds real traffic (a low seed would make
                     # the deadline trigger fire too late until the EMA
                     # catches up).
-                    iters = float(np.mean(np.asarray(info.iterations)))
+                    iters = float(np.mean(info.iterations))
                     if self._icfg.tol > 0.0 and iters >= 1.0:
                         dt *= self._icfg.n_iter / iters
                     # warmed: this is a post-compile timing — it may seed
@@ -503,10 +512,11 @@ class SmootherServer:
         ys = np.asarray(ys)
         ys_p, rs = self._pad_bucket([ys], n_pad, 1)
         traj, info, ll_steps = self._fallback_run(ys_p, rs)
-        jax.block_until_ready(traj.mean)
-        mean = np.asarray(traj.mean[0, :len(ys) + 1])
-        ll = float(np.sum(np.asarray(ll_steps)[0, :len(ys)]))
-        code = int(np.asarray(info.code).reshape(-1)[0])
+        mean, ll_steps, code = jax.device_get(
+            (traj.mean, ll_steps, info.code))
+        mean = mean[0, :len(ys) + 1]
+        ll = float(np.sum(ll_steps[0, :len(ys)]))
+        code = int(code.reshape(-1)[0])
         healthy = (code != LANE_DIVERGED) and bool(np.isfinite(mean).all())
         return mean, ll, healthy
 
@@ -548,7 +558,7 @@ class SmootherServer:
                                       else VERDICT_DIVERGED)
                 store[r.req_id] = (m, ll)
         dt = time.perf_counter() - t0
-        iters = int(np.sum(np.asarray(info.iterations)[:len(batch)]))
+        iters = int(np.sum(info.iterations[:len(batch)]))
         return dt, outcomes, store, iters
 
     def serve_requests(self, requests: List[np.ndarray], emit=print) -> dict:

@@ -9,11 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import IteratedConfig, iterated_smoother
+from repro.core import LANE_DIVERGED, IteratedConfig, iterated_smoother
 from repro.launch.autobatch import FlushPolicy
 from repro.data import CoordinatedTurnConfig, make_coordinated_turn_model, \
     simulate_trajectory
-from repro.launch.serve import (SmootherServeConfig, SmootherServer,
+from repro.launch.serve import (LAUNCH_COUNTERS, SmootherServeConfig,
+                                SmootherServer, launch_counts,
                                 serve_smoother)
 
 
@@ -125,3 +126,85 @@ def test_f64_serving_refused_on_tpu(monkeypatch):
     with pytest.raises(ValueError, match="float64 serving does not run"):
         serve.serve_dtype(SmootherServeConfig(f64=True))
     assert serve.serve_dtype(SmootherServeConfig(f64=False)) == jnp.float32
+
+
+def _width4_server(model):
+    return SmootherServer(model, SmootherServeConfig(
+        max_batch=4, n_iter=3, tol=0.0, lm_lambda=1.0, f64=False))
+
+
+def test_warm_launch_with_new_lengths_compiles_nothing():
+    """Answers are cut from one host copy of the launch: track lengths
+    the warm launch never had, in a bucket it compiled, compile nothing
+    (slicing the device array per request compiled programs for each
+    new (lane, length))."""
+    from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+    model = make_coordinated_turn_model(CoordinatedTurnConfig())
+    server = _width4_server(model)
+    server.warmup([16], [4])
+    batches = [[np.zeros((n, model.ny)) for n in lengths]
+               for lengths in ([5, 9, 13], [6, 11, 14])]
+    compiles = []
+
+    def listen(event, duration, **kwargs):
+        if event == BACKEND_COMPILE_EVENT:
+            compiles.append(kwargs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for batch in batches:
+            means, *_ = server.smooth_batch(batch, 16, 4)
+            assert [m.shape[0] for m in means] == [len(y) + 1
+                                                   for y in batch]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
+def _diverge_lane_1(run):
+    """``run`` with lane 1 reported `LANE_DIVERGED` and lane 2's mean
+    not finite at its last (padded) step."""
+    def stub(ys, rs):
+        traj, info, ll_steps = run(ys, rs)
+        traj = traj._replace(mean=traj.mean.at[2, -1].set(jnp.nan))
+        info = info._replace(code=info.code.at[1].set(LANE_DIVERGED))
+        return traj, info, ll_steps
+    return stub
+
+
+@pytest.mark.parametrize("diverged", [False, True],
+                         ids=["padded", "diverged_lane"])
+def test_answers_are_the_launch_outputs_sliced_on_the_host(diverged):
+    """`smooth_batch` returns the jitted launch's own outputs, each real
+    lane's cut to its length on the host, bit for bit: a bucket padded
+    in time (9 and 13 of 16 steps) and in batch (3 real lanes of 4)."""
+    model = make_coordinated_turn_model(CoordinatedTurnConfig())
+    server = _width4_server(model)
+    if diverged:
+        server._run = _diverge_lane_1(server._run)
+    lengths = [16, 9, 13]
+    requests = [np.asarray(simulate_trajectory(
+        model, n, jax.random.PRNGKey(40 + i))[1])
+        for i, n in enumerate(lengths)]
+    traj, info, ll_steps = server._run(*server._pad_bucket(requests, 16, 4))
+    mean, ll_steps, code, iterations = (
+        np.asarray(a) for a in (traj.mean, ll_steps, info.code,
+                                info.iterations))
+
+    means, got_info, lls, health = server.smooth_batch(requests, 16, 4)
+
+    assert len(means) == len(lls) == len(health) == 3
+    for i, n in enumerate(lengths):
+        np.testing.assert_array_equal(means[i], mean[i, :n + 1])
+        assert lls[i] == float(np.sum(ll_steps[i, :n]))
+    assert health == [bool(code[i] != LANE_DIVERGED)
+                      and bool(np.isfinite(mean[i, :n + 1]).all())
+                      for i, n in enumerate(lengths)]
+    assert health == ([True, False, True] if diverged else [True] * 3)
+    assert isinstance(got_info.code, np.ndarray)
+    assert isinstance(got_info.iterations, np.ndarray)
+    np.testing.assert_array_equal(got_info.code, code)
+    np.testing.assert_array_equal(got_info.iterations, iterations)
+    want = launch_counts(16, 4, lengths, iterations)
+    assert server.counters == {k: want[k] for k in LAUNCH_COUNTERS}

@@ -177,6 +177,21 @@ def _host_events(trace_dir):
             if e.name.startswith("serve.")]
 
 
+def _fetched_bytes(server, requests, n_pad, b_pad):
+    """The bytes of a launch's host-bound outputs: its means, per-step
+    log-likelihoods and `LaneStatus`, never its covariances."""
+    traj, info, ll_steps = jax.eval_shape(
+        server._run, *server._pad_bucket(requests, n_pad, b_pad))
+    return sum(a.size * a.dtype.itemsize
+               for a in (traj.mean, ll_steps, *info))
+
+
+def _unpack_args(events):
+    unpacks = [e for e in events if e[0] == "serve.unpack"]
+    assert len(unpacks) == 1
+    return unpacks[0][3]
+
+
 def test_launch_spans_nest(model, requests, tmp_path):
     server = _server(model)
     server.warmup([16], [4])          # compiles outside the trace
@@ -198,6 +213,9 @@ def test_launch_spans_nest(model, requests, tmp_path):
                                         "serve.wait", "serve.unpack"]
     assert all(lo <= s <= t <= hi for _, s, t, _ in children)
     assert all(a[2] <= b[1] for a, b in zip(children, children[1:]))
+    unpack = _unpack_args(events)
+    assert unpack["fetches"] == 1
+    assert unpack["bytes"] == _fetched_bytes(server, requests, 16, 4)
 
 
 def test_warmup_autotune_span(model, tmp_path):
@@ -235,3 +253,6 @@ def test_wide_launch_span_and_counter(tmp_path):
     args = launches[0][3]
     assert (args["nx"], args["m"], args["algebra"], args["lanes"]) \
         == (40, 80, "wide", 3)
+    unpack = _unpack_args(_host_events(tmp_path))
+    assert unpack["fetches"] == 1
+    assert unpack["bytes"] == _fetched_bytes(server, requests, 16, 4)
